@@ -7,7 +7,8 @@ Run:  PYTHONPATH=src python -m benchmarks.roofline_table [--records results/dryr
 cluster kernel (cluster_epoch_step / cluster_resize_step) from the
 fused_cluster benchmark artifact — launches, analytic bytes/launch,
 achieved bandwidth, fraction of the measured host copy bandwidth, and
-the HBM-bound time projected for the reference accelerator.
+the time the traffic would take at the published v5e HBM peak (a
+projection, not a device measurement).
 """
 import argparse
 import glob
@@ -34,7 +35,7 @@ def fused_table(path: str) -> None:
           f"host copy {art['host_copy_gb_s']:.1f} GB/s)")
     print()
     print("| kernel | launches | KB/launch | GB total | wall | "
-          "items/s | GB/s | host-bw% | HBM-bound |")
+          "items/s | GB/s | host-bw% | v5e HBM bound (projected) |")
     print("|---|---|---|---|---|---|---|---|---|")
     for k in art["kernels"]:
         ips = f"{k['items_per_s']:,.0f}" if k["items_per_s"] else "—"
@@ -44,7 +45,7 @@ def fused_table(path: str) -> None:
               f"| {ips} "
               f"| {k['achieved_gb_s']:.2f} "
               f"| {100*k['host_bw_frac']:.1f}% "
-              f"| {fmt_ms(k['tpu_projected_s']*1e3)} |")
+              f"| {fmt_ms(k['v5e_peak_bound_s']*1e3)} |")
 
 
 def main() -> None:

@@ -61,6 +61,7 @@ from repro.core.featurize import batch_job_features
 from repro.core.models import NNConfig
 from repro.core.pipeline import TasqConfig, TasqPipeline
 from repro.core.selection import select_jobs
+from repro.launch.cache import enable_compile_cache
 from repro.serve import AllocationService
 from repro.workloads import (TraceGenerator, build_corpus, execute,
                              observed_skyline, reexecute_fractions)
@@ -381,8 +382,8 @@ def bench_api_overhead(scale: float, pipeline: TasqPipeline) -> None:
     same cached compiled executable with pre-built padded arrays — the
     protocol layer must cost <5% on a 1k-request fused batch. Always runs
     at 1k requests (the contract's batch size), regardless of --scale."""
+    import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
     from repro.serve.batching import batch_bucket, pad_to
 
     assert "nn:lf2" in pipeline.models, \
@@ -407,7 +408,7 @@ def bench_api_overhead(scale: float, pipeline: TasqPipeline) -> None:
         padded = {"features": pad_to(np.asarray(feats), Bp)}
         obs_p = pad_to(np.asarray(observed, np.int64), Bp)
         fn = service._fused_fn(service._shape_sig(padded), True)
-        with enable_x64():
+        with jax.enable_x64(True):
             toks, a, b, rt = fn(model.params,
                                 {k: jnp.asarray(v) for k, v in padded.items()},
                                 jnp.asarray(obs_p))
@@ -1120,6 +1121,7 @@ def main() -> None:
                          "gauges, latency histograms) of every obs-enabled "
                          "benchmark")
     args = ap.parse_args()
+    enable_compile_cache()
     only = set(args.only.split(",")) if args.only else set(ALL)
     _OBS_SINK["trace_out"] = args.trace_out or None
     _OBS_SINK["metrics_out"] = args.metrics_out or None

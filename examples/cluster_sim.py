@@ -59,6 +59,7 @@ from repro.api import Allocator, AllocatorConfig
 from repro.cluster import ClusterConfig
 from repro.core.models import NNConfig
 from repro.core.pipeline import TasqConfig
+from repro.launch.cache import enable_compile_cache
 from repro.mlops import DriftMonitor, MLOpsLoop, RetrainController
 from repro.obs import Obs, write_trace
 from repro.workloads import DriftSpec, TraceGenerator
@@ -100,6 +101,7 @@ def main() -> None:
                          "hot-swap it in with zero decision downtime "
                          "(0 = retraining off)")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.shards < 1:
         ap.error("--shards must be >= 1")
     obs = Obs.enabled() if (args.trace_out or args.metrics_out) else None

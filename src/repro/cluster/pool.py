@@ -31,7 +31,6 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64
 
 from repro.kernels.ops import cluster_epoch_step
 from repro.serve.batching import node_bucket
@@ -91,7 +90,7 @@ class PoolShards:
         self.in_use = np.zeros(K, np.int64)
         # one-time upload; afterwards the device tables are only mutated by
         # resident kernels / small scatters of the changed slots
-        with enable_x64():
+        with jax.enable_x64(True):
             self._d_end = jnp.asarray(self._end_s)
             self._d_tok = jnp.asarray(self._tokens)
 
@@ -128,7 +127,7 @@ class PoolShards:
         toks_p = np.full(kp, new_tokens[0], np.int64)
         ends_p = np.full(kp, new_end_s[0], np.float64)
         slots_p[:k], toks_p[:k], ends_p[:k] = flat_slots, new_tokens, new_end_s
-        with enable_x64():    # end times must keep float64 resolution
+        with jax.enable_x64(True):    # end times must keep float64 resolution
             self._d_end, self._d_tok = _scatter_tables(
                 self._d_end, self._d_tok, jnp.asarray(slots_p),
                 jnp.asarray(toks_p), jnp.asarray(ends_p))
@@ -152,7 +151,7 @@ class PoolShards:
         self._query[sh, slot] = -1
         self.in_use -= freed
         assert np.all(self.in_use >= 0), self.in_use
-        with enable_x64():    # end times must keep float64 resolution
+        with jax.enable_x64(True):    # end times must keep float64 resolution
             self._d_end, self._d_tok = _expire_tables(
                 self._d_end, self._d_tok, float(now))
         return sh, qids, toks
@@ -298,7 +297,7 @@ class PoolShards:
         """
         q_tok = np.asarray(q_tok, np.int64)
         q_end = np.asarray(q_end, np.float64)
-        with enable_x64():
+        with jax.enable_x64(True):
             out = cluster_epoch_step(
                 self._d_end, self._d_tok, jnp.asarray(self.free),
                 jnp.asarray(q_tok), jnp.asarray(q_end), float(now),

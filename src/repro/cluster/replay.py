@@ -40,9 +40,9 @@ import dataclasses
 import time
 from typing import Dict, List, Optional, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64
 
 from repro.core.allocator import AllocationPolicy, choose_tokens_batch
 from repro.core.arepas import simulate_runtime_batch_jit
@@ -206,7 +206,7 @@ class FusedReplay:
         Q = node_bucket(min(cfg.queue_block, cfg.capacity // K))
         t0 = time.perf_counter()
         with self.obs.tracer.span("aot.warmup", scope="replay", K=K), \
-                enable_x64():
+                jax.enable_x64(True):
             d_end = jnp.full((K, L), jnp.inf, jnp.float64)
             d_tok = jnp.zeros((K, L), jnp.int64)
             warm = cluster_epoch_step(
@@ -260,89 +260,86 @@ class FusedReplay:
 
         in_use = 0
         o, tr = self.obs, self.obs.tracer
-        # optional jax.profiler capture alongside the host spans; entered
-        # manually so the (long) replay loop keeps its indentation
-        _prof = device_profile(o.profile_dir)
-        _prof.__enter__()
-        while events_left or any(q.size for q in queues) or in_use:
-            # idle fast-forward: nothing queued, nothing arriving this
-            # epoch -> jump to the next arrival or the earliest lease end
-            # (a device-side min; only the scalar crosses the boundary)
-            targets = []
-            if refill():
-                targets.append(float(buf[2][buf_at]))
-            if in_use:
-                targets.append(float(jnp.min(d_end)))
-            now = max(now + cfg.epoch_s, min(targets) if targets else now)
-            n_epochs += 1
+        # optional jax.profiler capture alongside the host spans
+        with device_profile(o.profile_dir):
+            while events_left or any(q.size for q in queues) or in_use:
+                # idle fast-forward: nothing queued, nothing arriving this
+                # epoch -> jump to the next arrival or the earliest lease end
+                # (a device-side min; only the scalar crosses the boundary)
+                targets = []
+                if refill():
+                    targets.append(float(buf[2][buf_at]))
+                if in_use:
+                    targets.append(float(jnp.min(d_end)))
+                now = max(now + cfg.epoch_s, min(targets) if targets else now)
+                n_epochs += 1
 
-            # drain arrivals <= now into per-shard queues, columnar
-            while refill():
-                arr = buf[2]
-                hi = int(np.searchsorted(arr[buf_at:], now, side="right"))
-                if hi == 0:
-                    break
-                sl = slice(buf_at, buf_at + hi)
-                backlog = sum(q.size for q in queues)
-                keep = hi
-                if backlog + hi > cfg.max_queue:
-                    keep = max(cfg.max_queue - backlog, 0)
-                    n_rejected += hi - keep
-                if keep:
-                    sl = slice(buf_at, buf_at + keep)
-                    sh = np.arange(sl.start, sl.stop) % K   # decision-free
-                    for k in range(K):
-                        m = sh == k
-                        queues[k].push(buf[0][sl][m], buf[1][sl][m])
-                buf_at += hi
-                events_left -= hi
+                # drain arrivals <= now into per-shard queues, columnar
+                while refill():
+                    arr = buf[2]
+                    hi = int(np.searchsorted(arr[buf_at:], now, side="right"))
+                    if hi == 0:
+                        break
+                    sl = slice(buf_at, buf_at + hi)
+                    backlog = sum(q.size for q in queues)
+                    keep = hi
+                    if backlog + hi > cfg.max_queue:
+                        keep = max(cfg.max_queue - backlog, 0)
+                        n_rejected += hi - keep
+                    if keep:
+                        sl = slice(buf_at, buf_at + keep)
+                        sh = np.arange(sl.start, sl.stop) % K   # decision-free
+                        for k in range(K):
+                            m = sh == k
+                            queues[k].push(buf[0][sl][m], buf[1][sl][m])
+                    buf_at += hi
+                    events_left -= hi
 
-            # one fused launch: expire -> release -> admit -> scatter
-            q_tok_m[:] = 0
-            q_end_m[:] = 0
-            heads = [q.head(Q) for q in queues]
-            for k, h in enumerate(heads):
-                m = h.shape[0]
-                if m:
-                    q_tok_m[k, :m] = h[:, 0]
-                    q_end_m[k, :m] = now + h[:, 1]
-            t0 = time.perf_counter()
-            with tr.span("cluster_epoch_step") as sp, enable_x64():
-                d_end, d_tok, _, n_admit, adm_tok, freed, n_exp = \
-                    cluster_epoch_step(
-                        d_end, d_tok, jnp.asarray(free),
-                        jnp.asarray(q_tok_m), jnp.asarray(q_end_m),
-                        now, impl=cfg.impl)
-                n_admit = np.asarray(n_admit)
-                adm_tok = np.asarray(adm_tok)
-                freed = np.asarray(freed)
-                n_exp = np.asarray(n_exp)
-                if sp is not None:
-                    # fence the resident tables too, so the span measures
-                    # device completion of the whole launch, not dispatch
-                    fence((d_end, d_tok))
-                    sp.attrs.update(admitted=int(n_admit.sum()),
-                                    expired=int(n_exp.sum()))
-            dt = time.perf_counter() - t0
-            kernel_s += dt
-            o.metrics.histogram("epoch_launch_s").record(dt)
-            launches += 1
-            for k in range(K):
-                queues[k].pop(int(n_admit[k]))
-            free += freed.astype(np.int64) - adm_tok.astype(np.int64)
-            n_admitted += int(n_admit.sum())
-            n_completed += int(n_exp.sum())
-            in_use = cfg.capacity - int(free.sum())
-            util_sum += in_use / cfg.capacity
-            if tr.enabled:   # per-shard lanes for the Perfetto timeline
-                tr.sample("pool_in_use",
-                          **{f"shard{k}": int(cfg.capacity // K - free[k])
-                             for k in range(K)})
-                tr.sample("queue_depth", **{f"shard{k}": queues[k].size
-                                            for k in range(K)})
-                tr.point("epoch", t_sim=now, admitted=int(n_admit.sum()))
+                # one fused launch: expire -> release -> admit -> scatter
+                q_tok_m[:] = 0
+                q_end_m[:] = 0
+                heads = [q.head(Q) for q in queues]
+                for k, h in enumerate(heads):
+                    m = h.shape[0]
+                    if m:
+                        q_tok_m[k, :m] = h[:, 0]
+                        q_end_m[k, :m] = now + h[:, 1]
+                t0 = time.perf_counter()
+                with tr.span("cluster_epoch_step") as sp, jax.enable_x64(True):
+                    d_end, d_tok, _, n_admit, adm_tok, freed, n_exp = \
+                        cluster_epoch_step(
+                            d_end, d_tok, jnp.asarray(free),
+                            jnp.asarray(q_tok_m), jnp.asarray(q_end_m),
+                            now, impl=cfg.impl)
+                    n_admit = np.asarray(n_admit)
+                    adm_tok = np.asarray(adm_tok)
+                    freed = np.asarray(freed)
+                    n_exp = np.asarray(n_exp)
+                    if sp is not None:
+                        # fence the resident tables too, so the span measures
+                        # device completion of the whole launch, not dispatch
+                        fence((d_end, d_tok))
+                        sp.attrs.update(admitted=int(n_admit.sum()),
+                                        expired=int(n_exp.sum()))
+                dt = time.perf_counter() - t0
+                kernel_s += dt
+                o.metrics.histogram("epoch_launch_s").record(dt)
+                launches += 1
+                for k in range(K):
+                    queues[k].pop(int(n_admit[k]))
+                free += freed.astype(np.int64) - adm_tok.astype(np.int64)
+                n_admitted += int(n_admit.sum())
+                n_completed += int(n_exp.sum())
+                in_use = cfg.capacity - int(free.sum())
+                util_sum += in_use / cfg.capacity
+                if tr.enabled:   # per-shard lanes for the Perfetto timeline
+                    tr.sample("pool_in_use",
+                              **{f"shard{k}": int(cfg.capacity // K - free[k])
+                                 for k in range(K)})
+                    tr.sample("queue_depth", **{f"shard{k}": queues[k].size
+                                                for k in range(K)})
+                    tr.point("epoch", t_sim=now, admitted=int(n_admit.sum()))
 
-        _prof.__exit__(None, None, None)
         wall = time.time() - t_wall
         o.metrics.counter("replay_admitted").inc(n_admitted)
         o.metrics.counter("replay_completed").inc(n_completed)

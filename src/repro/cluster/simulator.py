@@ -53,9 +53,9 @@ import time
 import warnings
 from typing import Dict, List, Optional, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64
 
 from repro.api.types import AllocationRequest, DecisionContext
 from repro.cluster.metrics import ClusterMetrics
@@ -903,9 +903,15 @@ class ClusterSimulator:
                     q_tok_m[k, :q.size] = tok_q[q]
                     q_end_m[k, :q.size] = now + rt_q[q]
                 # pool.admit_epoch reads the kernel outputs back to host, so
-                # the span closes at device completion, not dispatch
+                # the span closes at device completion, not dispatch. The
+                # float64 jnp twin on every backend: the pool's host mirror
+                # keeps f64 lease end times, and the Pallas kernel's f32
+                # tables can expire a lease whose end is within an f32 ulp
+                # of ``now`` one epoch early — its slot would then be
+                # handed out while the host still holds the lease.
                 with tr.span("cluster_epoch_step", fused=True, Q=int(Qp)):
-                    n_adm = pool.admit_epoch(now, q_ids_m, q_tok_m, q_end_m)
+                    n_adm = pool.admit_epoch(now, q_ids_m, q_tok_m, q_end_m,
+                                             impl="jnp")
                 for k in elig:
                     j = int(n_adm[k])
                     if j:
@@ -1081,15 +1087,19 @@ class ClusterSimulator:
                                  np.ndarray]:
         """One fused launch for a batch of resize/re-price candidates:
         priced allocation decision + deadline floor + AREPAS re-simulation
-        + lease repricing (kernels/cluster_step.py). Float64 on CPU —
+        + lease repricing (kernels/cluster_step.py). Float64 —
         decisions and end times bitwise-equal to the unfused
         decide/floor/_true_runtimes cascade. Returns numpy
         (tgt, sel, rt, new_end), each (C,)."""
         C = a.shape[0]
         Cp = batch_bucket(C)
         # outputs are read back to numpy inside the span, so it closes at
-        # device completion (the fence the exporter's timeline relies on)
-        with self.obs.tracer.span("cluster_resize_step", C=C), enable_x64():
+        # device completion (the fence the exporter's timeline relies on).
+        # The float64 jnp twin on every backend: the repriced end times
+        # land in the pool's f64 lease tables, which the f32 Pallas kernel
+        # would round.
+        with self.obs.tracer.span("cluster_resize_step", C=C), \
+                jax.enable_x64(True):
             tgt, sel, rt, new_end = cluster_resize_step(
                 jnp.asarray(pad_to(a, Cp)), jnp.asarray(pad_to(b, Cp)),
                 jnp.asarray(pad_to(price, Cp)),

@@ -14,7 +14,7 @@ skylines (the "(estimated) impact" of the paper).
 Each numpy policy has a jnp twin (``choose_tokens_jnp`` /
 ``min_tokens_within_slowdown_jnp``): vectorized fixed-iteration bisections
 that jit/vmap for the serving hot path and — run in float64 via
-``jax.experimental.enable_x64`` — return decisions bitwise-equal to the
+``jax.enable_x64`` — return decisions bitwise-equal to the
 scalar oracles (tests/test_alloc_parity.py). ``choose_tokens_batch`` is the
 host-side convenience wrapper.
 
@@ -126,7 +126,8 @@ def choose_tokens_jnp(a: jax.Array, b: jax.Array, policy: AllocationPolicy,
 
     The policy is static (branching on ``max_slowdown`` happens at trace
     time); ``observed_tokens`` is an optional (J,) int array. Trace under
-    ``enable_x64`` with float64 (a, b) for bitwise parity with the oracle.
+    ``jax.enable_x64`` with float64 (a, b) for bitwise parity with the
+    oracle.
     Same neutral-price delegation as the scalar.
     """
     a = jnp.asarray(a)
@@ -147,8 +148,7 @@ def choose_tokens_batch(a: np.ndarray, b: np.ndarray,
                         ) -> np.ndarray:
     """Batched allocation decisions, bitwise-equal to a ``choose_tokens``
     loop: one jitted float64 call over (J,) parameter arrays."""
-    from jax.experimental import enable_x64
-    with enable_x64():
+    with jax.enable_x64(True):
         aj = jnp.asarray(np.asarray(a, np.float64))
         bj = jnp.asarray(np.asarray(b, np.float64))
         obs = (None if observed_tokens is None
@@ -223,9 +223,19 @@ def choose_tokens_priced_jnp(a: jax.Array, b: jax.Array,
         return (jnp.where(cond & ~ok, mid + 1, lo),
                 jnp.where(cond & ok, mid, hi_s))
 
-    lo, _ = jax.lax.fori_loop(0, _BISECT_ITERS, body,
-                              (jnp.full(a.shape, lo0, jnp.int64), hi))
+    # Under ``jax.shard_map`` the body's outputs vary over the mesh axes of
+    # (a, b, price, observed); the initial carry must vary the same way.
+    init = tuple(_vary_like(x, limit)
+                 for x in (jnp.full(a.shape, lo0, jnp.int64), hi))
+    lo, _ = jax.lax.fori_loop(0, _BISECT_ITERS, body, init)
     return jnp.maximum(jnp.minimum(t_gain, policy.max_tokens), lo)
+
+
+def _vary_like(x: jax.Array, ref: jax.Array) -> jax.Array:
+    """``x`` marked as varying over every manual mesh axis ``ref`` varies
+    over (a no-op outside ``shard_map``)."""
+    missing = tuple(jax.typeof(ref).vma - jax.typeof(x).vma)
+    return jax.lax.pcast(x, missing, to="varying") if missing else x
 
 
 @functools.lru_cache(maxsize=None)
@@ -242,8 +252,7 @@ def choose_tokens_priced_batch(a: np.ndarray, b: np.ndarray,
                                ) -> np.ndarray:
     """Batched priced decisions, bitwise-equal to a ``choose_tokens_priced``
     loop: one jitted float64 call over (J,) parameter/price arrays."""
-    from jax.experimental import enable_x64
-    with enable_x64():
+    with jax.enable_x64(True):
         aj = jnp.asarray(np.asarray(a, np.float64))
         bj = jnp.asarray(np.asarray(b, np.float64))
         pj = jnp.asarray(np.asarray(price, np.float64))

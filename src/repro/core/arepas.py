@@ -30,6 +30,7 @@ import jax.numpy as jnp
 import numpy as np
 
 __all__ = [
+    "floor_div",
     "simulate_skyline",
     "simulate_runtime",
     "simulate_runtime_jax",
@@ -85,6 +86,16 @@ def peak_allocation(skyline: np.ndarray) -> int:
 
 
 # ------------------------------------------------------------ jax vectorized --
+def floor_div(x, n):
+    """Exact ``floor(x / n)`` for integer-valued f32 ``x >= 0`` and
+    ``n >= 1`` below 2^24: the quotient is corrected by exact integer
+    products, so it holds whether or not the backend's division is
+    correctly rounded."""
+    q = jnp.floor(x / n)
+    q = jnp.where(q * n > x, q - 1.0, q)
+    return jnp.where((q + 1.0) * n <= x, q + 1.0, q)
+
+
 def simulate_runtime_jax(skyline: jax.Array, valid_len: jax.Array,
                          new_alloc: jax.Array) -> jax.Array:
     """Vectorizable/jittable runtime simulation (exact vs the numpy oracle).
@@ -99,9 +110,8 @@ def simulate_runtime_jax(skyline: jax.Array, valid_len: jax.Array,
     segment_sum; runtime = (#under seconds) + sum_over floor(area / Nt).
 
     Exactness: skylines are integer token counts, so areas are integers
-    (< 2^24, exactly representable in f32). f32 division of exact ints is
-    correctly rounded, so ``floor(area/nt + 1e-6)`` equals the exact integer
-    floor for nt < 1e6 — bitwise-equal to the numpy/f64 oracle.
+    (< 2^24, exactly representable in f32), and ``floor_div`` takes their
+    exact integer floor — bitwise-equal to the numpy/f64 oracle.
     """
     s = skyline.astype(jnp.float32)
     smax = s.shape[0]
@@ -123,7 +133,7 @@ def simulate_runtime_jax(skyline: jax.Array, valid_len: jax.Array,
                                    num_segments=smax)
     seg_is_over = jax.ops.segment_max(over.astype(jnp.int32), seg_id,
                                       num_segments=smax)
-    over_len = jnp.sum(jnp.floor(seg_area / nt + 1e-6) * seg_is_over)
+    over_len = jnp.sum(floor_div(seg_area, nt) * seg_is_over)
     return (over_len + jnp.sum(under)).astype(jnp.int32)
 
 

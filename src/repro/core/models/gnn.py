@@ -76,5 +76,6 @@ def gnn_apply(params: Dict, model_in: Dict[str, jax.Array]) -> jax.Array:
 
 
 def make_gnn(in_dim: int, cfg: GNNConfig):
-    params = init_gnn(jax.random.PRNGKey(cfg.seed), in_dim, cfg)
+    with jax.threefry_partitionable(False):     # as make_nn's weights
+        params = init_gnn(jax.random.PRNGKey(cfg.seed), in_dim, cfg)
     return params, gnn_apply

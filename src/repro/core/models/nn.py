@@ -107,7 +107,12 @@ def fit_model(apply_fn: Callable, params: Any, inputs: Dict[str, np.ndarray],
 
 def make_nn(in_dim: int, cfg: NNConfig):
     """Returns (params, apply) for the job-level-feature MLP."""
-    params = init_mlp(jax.random.PRNGKey(cfg.seed), in_dim, cfg.hidden)
+    # Initial weights come from the non-partitionable threefry stream: the
+    # one the engines were tuned on. jax 0.5 made the partitionable stream
+    # the default, which draws other weights from the same seed.
+    with jax.threefry_partitionable(False):
+        params = init_mlp(jax.random.PRNGKey(cfg.seed), in_dim, cfg.hidden)
+
     def apply(p, model_in):
         return mlp_apply(p, model_in["features"])
     return params, apply

@@ -5,8 +5,9 @@
   skyline         — bulk AREPAS skyline simulation (TASQ data augmentation)
   cluster_step    — fused cluster epoch step + elastic resize (replay loop)
 
-Each kernel has a pure-jnp oracle in ref.py and a jit'd wrapper in ops.py;
-interpret=True executes the kernel body on CPU for correctness testing.
+Each kernel has a pure-jnp reference and a wrapper in ops.py. On a TPU the
+kernels compile through Mosaic; on the CPU, interpret=True executes the
+kernel body for the tests.
 """
 from repro.kernels.ops import (arepas_runtimes, cluster_epoch_step,
                                cluster_resize_step, flash_attention,

@@ -12,11 +12,12 @@ Two kernels, each with a pure-jnp twin:
 
   * ``epoch_step_pallas`` / ``epoch_step_ref`` — the fused epoch step. Grid
     (K, 2, L-blocks) with a two-phase sweep per shard: phase 0 scans expiry
-    and accumulates the freed-token total in VMEM carry; phase 1 re-derives
-    the expiry mask per block (idempotent), turns the policy-ordered queue
-    into an admitted prefix via an in-VMEM cumsum against ``free + freed``,
-    and scatters admitted leases into free slots with a one-hot matmul
-    (slot rank x queue rank on the MXU — TPUs hate scatters).
+    and accumulates the freed-token total in SMEM carries; phase 1
+    re-derives the expiry mask per block (idempotent), turns the
+    policy-ordered queue into an admitted prefix via a lane prefix sum
+    against ``free + freed``, and scatters admitted leases into free slots
+    with a one-hot matmul (queue rank x slot on the MXU — TPUs hate
+    scatters).
   * ``resize_step_pallas`` / ``resize_step_ref`` — the fused elastic-resize
     path: the priced allocation decision (gain cut-off + fixed-iteration
     slowdown bisection, core/allocator.py) runs in the first time-block,
@@ -25,13 +26,16 @@ Two kernels, each with a pure-jnp twin:
     pressure event instead of a decide -> simulate -> reprice cascade.
 
 Exactness: token counts, slot ranks and AREPAS areas are integers < 2^24,
-exact in f32 (same argument as kernels/skyline.py). Lease *end times* in the
-Pallas kernels are f32 — Mosaic has no f64 — so the f32 kernels trade time
-resolution for bandwidth; the jnp twins are dtype-generic and, run in
-float64 under ``jax.experimental.enable_x64``, are bitwise-identical to the
-unfused epoch loop (tests/test_cluster.py parity matrix). On the CPU
-container the twins *are* the fused hot path (one XLA fusion per epoch);
-the Pallas kernels run under ``interpret=True`` for correctness testing.
+exact in f32 (same argument as kernels/skyline.py), and the one-hot matmuls
+run at full f32 precision. Lease *end times* in the Pallas kernels are f32
+— Mosaic has no f64 — so they are exact only where times are: whole
+seconds below 2^24, as in ``FusedReplay``. The jnp twins are dtype-generic
+and, run in float64 under ``jax.enable_x64``, are bitwise-identical to the
+unfused epoch loop (tests/test_cluster.py parity matrix); the simulator,
+whose pool keeps float64 end times, runs them on every backend.
+
+The Pallas kernels compile for the TPU (tests/test_tpu_compile.py) and run
+under ``interpret=True`` on the CPU, for the tests.
 """
 from __future__ import annotations
 
@@ -45,12 +49,14 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core.allocator import _BISECT_ITERS, AllocationPolicy
 from repro.core.allocator import choose_tokens_priced_jnp
 from repro.core.arepas import simulate_runtime_batch
+from repro.kernels.skyline import (arepas_block, arepas_runtime, call_x32,
+                                   lane_cumsum, lane_iota, onehot_matmul)
 
 __all__ = ["epoch_step_ref", "epoch_step_pallas",
            "resize_step_ref", "resize_step_pallas",
            "EPOCH_STEP_SUPPORTS_PREEMPTION"]
 
-DEFAULT_LEASE_BLOCK = 256
+DEFAULT_LEASE_BLOCK = 128
 
 # The fused epoch step has no preempt phase: it expires, releases, admits
 # and scatters, but cannot checkpoint a victim lease's remaining work back
@@ -144,89 +150,86 @@ def resize_step_ref(a: jax.Array, b: jax.Array, price: jax.Array,
 
 
 # ------------------------------------------------- fused epoch kernel -------
+# Layout: every vector is a (1, n) row on the lanes. The (K, L) tables and
+# (K, Q) queue heads are viewed as (K, 1, n) with the shard dim squeezed,
+# so each block is a whole-height (1, n) tile; per-shard scalars (free,
+# the outputs' (K,) totals, the carries) and ``now`` live in SMEM.
 def _epoch_kernel(end_ref, tok_ref, free_ref, qtok_ref, qend_ref, now_ref,
                   nend_ref, ntok_ref, slot_ref, nadm_ref, admtok_ref,
                   freed_ref, nexp_ref, carry_ref, slot_acc, *,
                   lblock: int, n_lblocks: int, n_queue: int):
+    k = pl.program_id(0)
     p = pl.program_id(1)                  # 0: expiry scan, 1: admit+scatter
     t = pl.program_id(2)
 
+    # carry: 0 freed tokens, 1 expired leases, 2 running free-slot rank,
+    # 3 admitted count, 4 admitted tokens, 5 open slots after expiry
     @pl.when((p == 0) & (t == 0))
     def _init():
-        carry_ref[...] = jnp.zeros_like(carry_ref)
+        for i in range(6):
+            carry_ref[i] = 0.0
         slot_acc[...] = jnp.zeros_like(slot_acc)
 
-    now = now_ref[0, 0]
-    end = end_ref[0]
-    tok = tok_ref[0]
+    now = now_ref[0]
+    end = end_ref[...]
+    tok = tok_ref[...]
     expired = (tok > 0.0) & (end <= now)
     tok1 = jnp.where(expired, 0.0, tok)
     end1 = jnp.where(expired, jnp.inf, end)
+    free_slot = (tok1 == 0.0).astype(jnp.float32)
 
     @pl.when(p == 0)
     def _phase_expire():
         carry_ref[0] = carry_ref[0] + jnp.sum(jnp.where(expired, tok, 0.0))
         carry_ref[1] = carry_ref[1] + jnp.sum(expired.astype(jnp.float32))
-        carry_ref[5] = carry_ref[5] + jnp.sum((tok1 == 0.0)
-                                              .astype(jnp.float32))
-        nend_ref[0] = end1
-        ntok_ref[0] = tok1
+        carry_ref[5] = carry_ref[5] + jnp.sum(free_slot)
 
     # Admission decision once per shard: the queue row fits in VMEM, so the
-    # prefix-sum fit test is a single cumsum against free + freed, capped
-    # by the open lease slots counted during the expiry phase.
+    # prefix-sum fit test is a single lane prefix sum against free + freed,
+    # capped by the open lease slots counted during the expiry phase.
     @pl.when((p == 1) & (t == 0))
     def _decide():
-        qt = qtok_ref[0]
-        free_after = free_ref[0] + carry_ref[0]
-        csum = jnp.cumsum(qt)
-        qidx = jax.lax.iota(jnp.float32, n_queue)
-        adm = (csum <= free_after) & (qt > 0.0) & (qidx < carry_ref[5])
-        carry_ref[2] = 0.0                               # running free rank
-        carry_ref[3] = jnp.sum(adm.astype(jnp.float32))  # n_admit
-        carry_ref[4] = jnp.sum(jnp.where(adm, qt, 0.0))  # admitted tokens
+        qt = qtok_ref[...]
+        free_after = free_ref[k] + carry_ref[0]
+        qidx = lane_iota(n_queue, jnp.float32)
+        adm = ((lane_cumsum(qt) <= free_after) & (qt > 0.0)
+               & (qidx < carry_ref[5]))
+        carry_ref[2] = 0.0
+        carry_ref[3] = jnp.sum(adm.astype(jnp.float32))
+        carry_ref[4] = jnp.sum(jnp.where(adm, qt, 0.0))
 
     @pl.when(p == 1)
     def _phase_admit():
-        qt = qtok_ref[0]
-        qe = qend_ref[0]
-        n_admit = carry_ref[3]
         rank_base = carry_ref[2]
-        free_slot = tok1 == 0.0
-        rank = rank_base + jnp.cumsum(free_slot.astype(jnp.float32)) - 1.0
-        take = free_slot & (rank < n_admit)
+        rank = rank_base + lane_cumsum(free_slot) - 1.0          # (1, Lb)
+        take = (free_slot > 0.0) & (rank < carry_ref[3])
 
-        # queue-rank -> slot gather as a one-hot matmul (ranks are exact
-        # integer f32 < 2^24, so the equality test is exact)
-        qidx = jax.lax.iota(jnp.float32, n_queue)
-        oh = ((rank[:, None] == qidx[None, :]) &
-              take[:, None]).astype(jnp.float32)         # (Lb, Q)
-        val_tok = jax.lax.dot_general(
-            oh, qt, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        val_end = jax.lax.dot_general(
-            oh, qe, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        ntok_ref[0] = jnp.where(take, val_tok, tok1)
-        nend_ref[0] = jnp.where(take, val_end, end1)
+        # queue-rank -> slot gather as a (Q, Lb) one-hot matmul (ranks are
+        # exact integers in f32, so the equality test is exact)
+        qrows = jax.lax.broadcasted_iota(jnp.int32, (n_queue, lblock), 0)
+        oh = ((qrows == rank.astype(jnp.int32)) & take).astype(jnp.float32)
+        ntok_ref[...] = jnp.where(take, onehot_matmul(qtok_ref[...], oh, 0),
+                                  tok1)
+        nend_ref[...] = jnp.where(take, onehot_matmul(qend_ref[...], oh, 0),
+                                  end1)
 
         # slot-of inverse: accumulate (slot index + 1) per queue rank
-        lidx = (t * lblock + jax.lax.iota(jnp.int32, lblock)
-                ).astype(jnp.float32)
-        slot_acc[...] = slot_acc[...] + jax.lax.dot_general(
-            oh, lidx + 1.0, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        carry_ref[2] = rank_base + jnp.sum(free_slot.astype(jnp.float32))
+        lidx = (t * lblock + lane_iota(lblock)).astype(jnp.float32)
+        slot_acc[...] = slot_acc[...] + onehot_matmul(lidx + 1.0, oh, 1)
+        carry_ref[2] = rank_base + jnp.sum(free_slot)
 
     @pl.when((p == 1) & (t == n_lblocks - 1))
     def _finalize():
-        slot_ref[0] = (slot_acc[...] - 1.0).astype(jnp.int32)
-        nadm_ref[0] = carry_ref[3].astype(jnp.int32)
-        admtok_ref[0] = carry_ref[4].astype(jnp.int32)
-        freed_ref[0] = carry_ref[0].astype(jnp.int32)
-        nexp_ref[0] = carry_ref[1].astype(jnp.int32)
+        slot_ref[...] = (slot_acc[...] - 1.0).astype(jnp.int32)
+        nadm_ref[k] = carry_ref[3].astype(jnp.int32)
+        admtok_ref[k] = carry_ref[4].astype(jnp.int32)
+        freed_ref[k] = carry_ref[0].astype(jnp.int32)
+        nexp_ref[k] = carry_ref[1].astype(jnp.int32)
 
 
+# jitted: the kernel body is a fresh closure on every trace, so an eager
+# call would lower and compile the Mosaic kernel again at every launch
+@functools.partial(jax.jit, static_argnames=("lease_block", "interpret"))
 def epoch_step_pallas(end_s: jax.Array, tokens: jax.Array, free: jax.Array,
                       q_tok: jax.Array, q_end: jax.Array, now: jax.Array, *,
                       lease_block: int = DEFAULT_LEASE_BLOCK,
@@ -243,45 +246,37 @@ def epoch_step_pallas(end_s: jax.Array, tokens: jax.Array, free: jax.Array,
 
     kernel = functools.partial(_epoch_kernel, lblock=lb, n_lblocks=nlb,
                                n_queue=Q)
-    out = pl.pallas_call(
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    lease = pl.BlockSpec((None, 1, lb), lambda k, p, t: (k, 0, t))
+    # the admit phase writes every lease block; during the expiry phase the
+    # output stays parked on block 0, so nothing is written back twice
+    lease_out = pl.BlockSpec((None, 1, lb), lambda k, p, t: (k, 0, t * p))
+    queue = pl.BlockSpec((None, 1, Q), lambda k, p, t: (k, 0, 0))
+    rows = lambda x: x.astype(jnp.float32).reshape(K, 1, -1)
+    call = pl.pallas_call(
         kernel,
         grid=(K, 2, nlb),
-        in_specs=[
-            pl.BlockSpec((1, lb), lambda k, p, t: (k, t)),
-            pl.BlockSpec((1, lb), lambda k, p, t: (k, t)),
-            pl.BlockSpec((1,), lambda k, p, t: (k,)),
-            pl.BlockSpec((1, Q), lambda k, p, t: (k, 0)),
-            pl.BlockSpec((1, Q), lambda k, p, t: (k, 0)),
-            pl.BlockSpec((1, 1), lambda k, p, t: (0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, lb), lambda k, p, t: (k, t)),
-            pl.BlockSpec((1, lb), lambda k, p, t: (k, t)),
-            pl.BlockSpec((1, Q), lambda k, p, t: (k, 0)),
-            pl.BlockSpec((1,), lambda k, p, t: (k,)),
-            pl.BlockSpec((1,), lambda k, p, t: (k,)),
-            pl.BlockSpec((1,), lambda k, p, t: (k,)),
-            pl.BlockSpec((1,), lambda k, p, t: (k,)),
-        ],
+        in_specs=[lease, lease, smem, queue, queue, smem],
+        out_specs=[lease_out, lease_out, queue, smem, smem, smem, smem],
         out_shape=[
-            jax.ShapeDtypeStruct((K, L), jnp.float32),
-            jax.ShapeDtypeStruct((K, L), jnp.float32),
-            jax.ShapeDtypeStruct((K, Q), jnp.int32),
+            jax.ShapeDtypeStruct((K, 1, L), jnp.float32),
+            jax.ShapeDtypeStruct((K, 1, L), jnp.float32),
+            jax.ShapeDtypeStruct((K, 1, Q), jnp.int32),
             jax.ShapeDtypeStruct((K,), jnp.int32),
             jax.ShapeDtypeStruct((K,), jnp.int32),
             jax.ShapeDtypeStruct((K,), jnp.int32),
             jax.ShapeDtypeStruct((K,), jnp.int32),
         ],
-        scratch_shapes=[pltpu.VMEM((8,), jnp.float32),
-                        pltpu.VMEM((Q,), jnp.float32)],
+        scratch_shapes=[pltpu.SMEM((8,), jnp.float32),
+                        pltpu.VMEM((1, Q), jnp.float32)],
         interpret=interpret,
-    )(end_s.astype(jnp.float32), tokens.astype(jnp.float32),
-      free.astype(jnp.float32), q_tok.astype(jnp.float32),
-      q_end.astype(jnp.float32),
-      jnp.asarray(now, jnp.float32).reshape(1, 1))
+    )
+    out = call_x32(call, rows(end_s), rows(tokens), free.astype(jnp.float32),
+                   rows(q_tok), rows(q_end),
+                   jnp.asarray(now, jnp.float32).reshape(1))
     new_end, new_tok_f, slot_of, n_admit, adm_tok, freed, n_expired = out
-    return (new_end, new_tok_f.astype(jnp.int32), slot_of, n_admit,
-            adm_tok, freed, n_expired)
+    return (new_end.reshape(K, L), new_tok_f.reshape(K, L).astype(jnp.int32),
+            slot_of.reshape(K, Q), n_admit, adm_tok, freed, n_expired)
 
 
 # ------------------------------------------------ fused resize kernel -------
@@ -291,17 +286,17 @@ def _resize_kernel(a_ref, b_ref, pr_ref, obs_ref, flr_ref, done_ref,
                    tblock: int, n_tblocks: int, epoch_s: float,
                    min_gain: float, max_slowdown: float, min_tokens: int,
                    max_tokens: int, cap: int):
+    c = pl.program_id(0)
     it = pl.program_id(1)
 
     # Decision preamble in the first time-block: gain cut-off + the same
     # fixed-iteration slowdown bisection as choose_tokens_priced_jnp, then
     # min(cap) / max(deadline floor) — carried as the AREPAS allocation.
+    # The scalar unit has no pow, so the decision runs on one lane row.
     @pl.when(it == 0)
     def _decide():
-        a = a_ref[0]
-        b = b_ref[0]
-        price = pr_ref[0]
-        hi = obs_ref[0]
+        row = lambda ref: jnp.full((1, 128), ref[c], jnp.float32)
+        a, b, price, hi = row(a_ref), row(b_ref), row(pr_ref), row(obs_ref)
         lo0 = jnp.float32(min_tokens)
         eff_gain = max(min_gain, 1e-9) * price
         t_gain = jnp.clip(jnp.round(jnp.abs(a) / eff_gain), lo0, hi)
@@ -317,78 +312,38 @@ def _resize_kernel(a_ref, b_ref, pr_ref, obs_ref, flr_ref, done_ref,
                 return (jnp.where(cond & ~ok, mid + 1, lo),
                         jnp.where(cond & ok, mid, hi_s))
 
-            lo, _ = jax.lax.fori_loop(0, _BISECT_ITERS, body, (lo0, hi))
+            lo, _ = jax.lax.fori_loop(0, _BISECT_ITERS, body,
+                                      (jnp.full_like(hi, lo0), hi))
             t_gain = jnp.maximum(jnp.minimum(t_gain, jnp.float32(max_tokens)),
                                  lo)
-        nt = jnp.maximum(jnp.minimum(t_gain, jnp.float32(cap)), flr_ref[0])
+        nt = jnp.maximum(jnp.minimum(t_gain, jnp.float32(cap)), flr_ref[c])
         carry_ref[0] = 0.0            # prev block ended over-cap
         carry_ref[1] = 0.0            # open over-section area
         carry_ref[2] = 0.0            # runtime accumulator
-        carry_ref[3] = nt
+        carry_ref[3] = jnp.max(nt)
 
     # Streaming AREPAS segmented reduction at the shrunk allocation — the
     # same carry-across-time-blocks scheme as kernels/skyline.py.
-    s = sky_ref[0].astype(jnp.float32)
     nt = carry_ref[3]
-    vlen = len_ref[0].astype(jnp.int32)
-
-    t0 = it * tblock
-    idx = t0 + jax.lax.iota(jnp.int32, tblock)
-    valid = idx < vlen
-    over = (s > nt) & valid
-
-    prev_over = carry_ref[0] > 0.5
-    open_area = carry_ref[1]
-    acc = carry_ref[2]
-
-    closes_at_edge = prev_over & ~over[0]
-    continues = prev_over & over[0]
-    acc = acc + jnp.where(closes_at_edge,
-                          jnp.floor(open_area / nt + 1e-6), 0.0)
-
-    prev = jnp.concatenate([over[:1], over[:-1]])
-    change = (over != prev).astype(jnp.int32)
-    seg_id = jnp.cumsum(change)
-
-    seg_ids = jax.lax.iota(jnp.int32, tblock)
-    onehot = (seg_id[None, :] == seg_ids[:, None])
-    areas = jax.lax.dot_general(
-        onehot.astype(jnp.float32), jnp.where(over, s, 0.0),
-        (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-    seg_over = jax.lax.dot_general(
-        onehot.astype(jnp.float32), over.astype(jnp.float32),
-        (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32) > 0.5
-
-    areas = areas + jnp.where((seg_ids == 0) & continues, open_area, 0.0)
-
-    last_seg = seg_id[-1]
-    is_open = (seg_ids == last_seg) & over[-1]
-    closed_over = seg_over & ~is_open
-
-    acc = acc + jnp.sum(jnp.where(closed_over,
-                                  jnp.floor(areas / nt + 1e-6), 0.0))
-    acc = acc + jnp.sum((~over & valid).astype(jnp.float32))
-
-    carry_ref[0] = over[-1].astype(jnp.float32)
-    carry_ref[1] = jnp.sum(jnp.where(is_open, areas, 0.0))
-    carry_ref[2] = acc
+    valid = it * tblock + lane_iota(tblock) < len_ref[c]
+    arepas_block(sky_ref[...], valid, nt, carry_ref)
 
     @pl.when(it == n_tblocks - 1)
     def _finalize():
-        final = carry_ref[2] + jnp.where(
-            carry_ref[0] > 0.5,
-            jnp.floor(carry_ref[1] / carry_ref[3] + 1e-6), 0.0)
-        rt = jnp.maximum(final, 1.0)
-        now = now_ref[0, 0]
-        nt_f = carry_ref[3]
-        sel = (nt_f < ctok_ref[0]) & ((cend_ref[0] - now) > epoch_s)
-        remaining = jnp.maximum(jnp.round(rt * (1.0 - done_ref[0])), 1.0)
-        tgt_ref[0] = nt_f.astype(jnp.int32)
-        sel_ref[0] = sel.astype(jnp.int32)
-        rt_ref[0] = rt.astype(jnp.int32)
-        nend_ref[0] = now + remaining
+        rt = jnp.maximum(arepas_runtime(carry_ref, nt), 1.0)
+        now = now_ref[0]
+        sel = (nt < ctok_ref[c]) & ((cend_ref[c] - now) > epoch_s)
+        remaining = jnp.maximum(
+            jnp.max(jnp.round(jnp.full((1, 128), rt * (1.0 - done_ref[c]),
+                                       jnp.float32))), 1.0)
+        tgt_ref[c] = nt.astype(jnp.int32)
+        sel_ref[c] = sel.astype(jnp.int32)
+        rt_ref[c] = rt.astype(jnp.int32)
+        nend_ref[c] = now + remaining
 
 
+@functools.partial(jax.jit, static_argnames=(
+    "epoch_s", "policy", "cap", "time_block", "interpret"))
 def resize_step_pallas(a: jax.Array, b: jax.Array, price: jax.Array,
                        obs: jax.Array, floor: jax.Array, done: jax.Array,
                        cand_tok: jax.Array, cand_end: jax.Array,
@@ -409,26 +364,25 @@ def resize_step_pallas(a: jax.Array, b: jax.Array, price: jax.Array,
         min_gain=policy.min_gain, max_slowdown=policy.max_slowdown,
         min_tokens=policy.min_tokens, max_tokens=policy.max_tokens,
         cap=int(cap))
-    vec = pl.BlockSpec((1,), lambda c, t: (c,))
-    return pl.pallas_call(
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    f32 = lambda x: jnp.asarray(x).astype(jnp.float32)
+    call = pl.pallas_call(
         kernel,
         grid=(C, ntb),
-        in_specs=[vec, vec, vec, vec, vec, vec, vec, vec,
-                  pl.BlockSpec((1, tb), lambda c, t: (c, t)),
-                  vec,
-                  pl.BlockSpec((1, 1), lambda c, t: (0, 0))],
-        out_specs=[vec, vec, vec, vec],
+        in_specs=[smem] * 8 + [
+            pl.BlockSpec((None, 1, tb), lambda c, t: (c, 0, t)),
+            smem, smem],
+        out_specs=[smem] * 4,
         out_shape=[
             jax.ShapeDtypeStruct((C,), jnp.int32),
             jax.ShapeDtypeStruct((C,), jnp.int32),
             jax.ShapeDtypeStruct((C,), jnp.int32),
             jax.ShapeDtypeStruct((C,), jnp.float32),
         ],
-        scratch_shapes=[pltpu.VMEM((4,), jnp.float32)],
+        scratch_shapes=[pltpu.SMEM((4,), jnp.float32)],
         interpret=interpret,
-    )(a.astype(jnp.float32), b.astype(jnp.float32),
-      price.astype(jnp.float32), obs.astype(jnp.float32),
-      floor.astype(jnp.float32), done.astype(jnp.float32),
-      cand_tok.astype(jnp.float32), cand_end.astype(jnp.float32),
-      sky.astype(jnp.float32), lens.astype(jnp.int32),
-      jnp.asarray(now, jnp.float32).reshape(1, 1))
+    )
+    return call_x32(call, f32(a), f32(b), f32(price), f32(obs), f32(floor),
+                    f32(done), f32(cand_tok), f32(cand_end),
+                    f32(sky).reshape(C, 1, Smax),
+                    jnp.asarray(lens).astype(jnp.int32), f32(now).reshape(1))

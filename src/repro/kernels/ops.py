@@ -1,9 +1,10 @@
 """Jit'd public wrappers for the Pallas kernels.
 
 Each wrapper handles layout (the model zoo uses (B, S, H, D); kernels take
-(B, H, S, D)), dtype promotion, and backend dispatch: on the CPU container
-kernels run in interpret mode (Python-level execution of the kernel body —
-the correctness contract); on TPU they compile via Mosaic.
+(B, H, S, D)), dtype promotion, and backend dispatch: on a TPU the kernels
+compile through Mosaic; on a CPU, which has no Mosaic, the model kernels and
+the skyline kernel run in interpret mode (Python-level execution of the
+kernel body, which the tests check against the references).
 """
 from __future__ import annotations
 
@@ -19,7 +20,7 @@ from repro.kernels import skyline as _sky
 from repro.kernels import ssd as _ssd
 
 __all__ = ["flash_attention", "ssd_scan", "arepas_runtimes",
-           "cluster_epoch_step", "cluster_resize_step"]
+           "cluster_epoch_step", "cluster_impl", "cluster_resize_step"]
 
 
 def _interpret_default() -> bool:
@@ -118,18 +119,22 @@ def arepas_runtimes(skylines: jax.Array, valid_lens: jax.Array,
 
 
 # -------------------------------------------------------- cluster epoch ---
-# Backend dispatch differs from the model kernels: the fused epoch twins are
-# dtype-generic jnp (float64-capable — the decision-parity contract), so on
-# CPU the hot path is the jitted twin (one XLA fusion per epoch) rather than
-# the interpreted Pallas body; on TPU the f32 Pallas kernel runs compiled.
-# impl: None (auto), "jnp", "pallas", "interpret".
+# impl: "pallas" (the f32 Mosaic kernel), "jnp" (the dtype-generic twin,
+# float64 under ``jax.enable_x64``), "interpret" (the Pallas body run by the
+# interpreter, for tests), or None: "pallas" on a TPU, and on a CPU — where
+# no Mosaic kernel can run — the jitted twin. A caller that needs the f64
+# twin on the TPU too passes impl="jnp" and says why.
 _epoch_step_jit = jax.jit(_cs.epoch_step_ref)
+_CLUSTER_IMPLS = ("jnp", "pallas", "interpret")
 
 
-def _cluster_impl(impl: Optional[str]) -> str:
+def cluster_impl(impl: Optional[str] = None) -> str:
+    """The kernel body ``impl`` resolves to on this backend."""
     if impl is None:
         return "jnp" if _interpret_default() else "pallas"
-    assert impl in ("jnp", "pallas", "interpret"), impl
+    if impl not in _CLUSTER_IMPLS:
+        raise ValueError(f"unknown cluster kernel impl {impl!r}; "
+                         f"known: {_CLUSTER_IMPLS}")
     return impl
 
 
@@ -142,7 +147,7 @@ def cluster_epoch_step(end_s: jax.Array, tokens: jax.Array, free: jax.Array,
     Returns (new_end, new_tok, slot_of, n_admit, adm_tok, freed, n_expired);
     see kernels/cluster_step.py for the contract.
     """
-    impl = _cluster_impl(impl)
+    impl = cluster_impl(impl)
     if impl == "jnp":
         return _epoch_step_jit(end_s, tokens, free, q_tok, q_end,
                                jnp.asarray(now, end_s.dtype))
@@ -168,7 +173,7 @@ def cluster_resize_step(a, b, price, obs, floor, done, cand_tok, cand_end,
     Returns (tgt, sel, rt, new_end) per candidate; see cluster_step.py.
     ``policy`` is an AllocationPolicy (hashable — jit caches per policy).
     """
-    impl = _cluster_impl(impl)
+    impl = cluster_impl(impl)
     if impl == "jnp":
         fn = _resize_step_jit(policy, int(cap), float(epoch_s))
         return fn(a, b, price, obs, floor, done, cand_tok, cand_end,

@@ -19,21 +19,29 @@ from __future__ import annotations
 from typing import Optional, Tuple
 
 import jax
+from jax.sharding import AxisType
 
 __all__ = ["make_allocation_mesh", "make_production_mesh", "make_smoke_mesh",
            "mesh_chips"]
 
 
+def _mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]):
+    # Auto axes: shardings are propagated by XLA and named only in
+    # ``with_sharding_constraint``/``shard_map`` specs (``jax.make_mesh``
+    # defaults to Explicit axes, which type every array's sharding)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _mesh(shape, axes)
 
 
 def make_smoke_mesh(shape: Tuple[int, ...] = (1, 1),
                     axes: Tuple[str, ...] = ("data", "model")):
     """Tiny mesh over however many devices the test process has."""
-    return jax.make_mesh(shape, axes)
+    return _mesh(shape, axes)
 
 
 def make_allocation_mesh(n_shards: int):
@@ -41,10 +49,10 @@ def make_allocation_mesh(n_shards: int):
     with one device per replica when the host has that many, else a
     ``make_smoke_mesh``-style 1-device mesh. The sharded service runs its
     batched kernels under ``jax.shard_map`` only when the mesh really
-    carries ``n_shards`` devices; on smaller hosts it falls back to
-    ``vmap`` over the shard axis (same math, one device)."""
+    carries ``n_shards`` devices; on smaller hosts it loops over the shard
+    axis (same math, one device)."""
     if n_shards >= 1 and len(jax.devices()) >= n_shards:
-        return jax.make_mesh((n_shards,), ("shard",))
+        return _mesh((n_shards,), ("shard",))
     return make_smoke_mesh((1,), ("shard",))
 
 

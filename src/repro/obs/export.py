@@ -46,15 +46,13 @@ def fence(x):
 @contextlib.contextmanager
 def device_profile(log_dir: Optional[str]):
     """Optionally capture a ``jax.profiler.trace`` alongside the host spans
-    (``None`` disables; profiler failures never take down the replay)."""
+    (``None`` disables). A profiler that cannot start raises, and so does
+    the body: a trace that was asked for is never silently missing."""
     if not log_dir:
         yield
         return
     import jax
-    try:
-        with jax.profiler.trace(log_dir):
-            yield
-    except Exception:                       # profiler backend unavailable
+    with jax.profiler.trace(log_dir):
         yield
 
 

@@ -233,7 +233,9 @@ class KernelRoofline:
 
     @property
     def bound_s(self) -> float:
-        """Memory-bound time on the reference accelerator's HBM."""
+        """Memory-bound time at ``hw``'s published HBM peak (v5e): a
+        projection from a constant, whatever device ran the launches —
+        not a measurement."""
         return self.total_bytes / self.hw.hbm_bw
 
     @property
@@ -263,7 +265,7 @@ class KernelRoofline:
             "achieved_gb_s": round(self.achieved_bw / 1e9, 4),
             "hbm_bound_frac": round(self.bound_fraction, 6),
             "host_bw_frac": round(self.host_fraction, 4),
-            "tpu_projected_s": round(self.bound_s, 6),
+            "v5e_peak_bound_s": round(self.bound_s, 6),
         }
 
 

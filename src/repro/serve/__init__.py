@@ -11,7 +11,7 @@ release). ``MicroBatcher`` queues single-job requests and drains them
 through ``decide`` in padded batches. ``ShardedAllocationService`` serves
 N replicas of one model behind the same protocol — shard-tagged rows are
 stacked into (K, Bp) blocks and decided in one compiled call under
-``jax.shard_map`` (``vmap`` on 1-device hosts), with ``ReplicaState``
+``jax.shard_map`` (a loop on 1-device hosts), with ``ReplicaState``
 keeping per-replica counters observable.
 
 The streaming serving plane (``repro.serve.plane`` / ``repro.serve.aot``)

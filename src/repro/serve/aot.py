@@ -27,14 +27,15 @@ tracks warmup cost as the grid grows.
 from __future__ import annotations
 
 import dataclasses
+import os
 import time
 import warnings
+from concurrent.futures import ThreadPoolExecutor
 from typing import Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64
 
 from repro.core.featurize import batch_graphs, batch_job_features
 from repro.obs import NULL_OBS, Obs
@@ -177,35 +178,60 @@ def _concrete(aval):
     return aval                           # already concrete (model params)
 
 
-def _aot_compile(raw_fn, avals: Tuple, donate: Tuple[int, ...],
-                 cfg: WarmupConfig, obs: Obs, kind: str, bucket: int
-                 ) -> Tuple[callable, ExecutableRecord]:
-    """``jit(raw).lower(*avals).compile()`` (+ one warm call): the
-    dryrun.py lower/compile pattern with per-stage timing. Donation is
-    restricted to argnums whose aval is a real array tree; XLA's
-    "donated buffers were not usable" advisory (CPU declines donation) is
+def _aot_compile_cells(cells, replica, cfg: WarmupConfig, obs: Obs,
+                       rep: WarmupReport) -> None:
+    """``jit(raw).lower(*avals).compile()`` (+ one warm call) for every
+    ``(kind, key, bucket, raw, avals, donate)`` cell whose key ``replica``
+    does not hold yet, pinning each result at its key.
+
+    Lowering traces, so it runs here, under the caller's x64 setting; the
+    compiles then run concurrently — XLA releases the GIL while it compiles,
+    and a TPU grid of float64 policy programs takes seconds per
+    executable. Warm calls and pins follow in cell order. Donation is
+    restricted to argnums whose aval is a real array tree; XLA's "donated
+    buffers were not usable" advisory (CPU declines donation) is
     suppressed — it is expected there, not actionable."""
-    donate_idx = tuple(i for i in donate
-                       if cfg.donate and avals[i] is not None)
-    t0 = time.perf_counter()
+    todo = []
+    for kind, key, bucket, raw, avals, donate in cells:
+        if key in replica.compiled:
+            rep.n_already_cached += 1
+        else:
+            todo.append((kind, key, bucket, raw, avals, donate))
+    if not todo:
+        return
+
+    def compile_one(lowered):
+        t0 = time.perf_counter()
+        return lowered.compile(), time.perf_counter() - t0
+
     with warnings.catch_warnings():
         warnings.filterwarnings(
             "ignore", message="Some donated buffers were not usable")
-        lowered = jax.jit(raw_fn, donate_argnums=donate_idx).lower(*avals)
-        t1 = time.perf_counter()
-        compiled = lowered.compile()
-        t2 = time.perf_counter()
-        t3 = t2
-        if cfg.warm:
-            out = compiled(*[_concrete(a) for a in avals])
-            jax.tree.map(lambda v: np.asarray(v), out)   # block until ready
-            t3 = time.perf_counter()
-    rec = ExecutableRecord(kind=kind, bucket=bucket, lower_s=t1 - t0,
-                           compile_s=t2 - t1, warm_s=t3 - t2)
-    obs.metrics.histogram("decision_cold_start_s").record(rec.total_s)
-    obs.tracer.point("aot.compile", kind=kind, bucket=bucket,
-                     compile_ms=round(rec.compile_s * 1e3, 1))
-    return compiled, rec
+        lowered, lower_s = [], []
+        for _, _, _, raw, avals, donate in todo:
+            donate_idx = tuple(i for i in donate
+                               if cfg.donate and avals[i] is not None)
+            t0 = time.perf_counter()
+            lowered.append(jax.jit(raw, donate_argnums=donate_idx)
+                           .lower(*avals))
+            lower_s.append(time.perf_counter() - t0)
+        workers = min(len(lowered), os.cpu_count() or 1)
+        with ThreadPoolExecutor(max_workers=workers) as pool:
+            built = list(pool.map(compile_one, lowered))
+        for (kind, key, bucket, _, avals, _), t_lower, (compiled, t_comp) \
+                in zip(todo, lower_s, built):
+            t0 = time.perf_counter()
+            if cfg.warm:
+                out = compiled(*[_concrete(a) for a in avals])
+                jax.tree.map(lambda v: np.asarray(v), out)  # block on it
+            rec = ExecutableRecord(kind=kind, bucket=bucket, lower_s=t_lower,
+                                   compile_s=t_comp,
+                                   warm_s=time.perf_counter() - t0)
+            obs.metrics.histogram("decision_cold_start_s").record(rec.total_s)
+            obs.tracer.point("aot.compile", kind=kind, bucket=bucket,
+                             compile_ms=round(rec.compile_s * 1e3, 1))
+            replica.install(key, compiled)
+            rep.add(rec)
 
 
 def warm_service(service: AllocationService,
@@ -222,7 +248,8 @@ def warm_service(service: AllocationService,
     rep = WarmupReport()
     t_wall = time.perf_counter()
     fused_ok = cfg.fused and service.model.supports_jit and template
-    with o.tracer.span("aot.warmup", scope="service"), enable_x64():
+    cells = []
+    with o.tracer.span("aot.warmup", scope="service"), jax.enable_x64(True):
         for Bp in cfg.bucket_set(service.batch_floor):
             f64 = _sds((Bp,), jnp.float64)
             for wo in cfg.observed:
@@ -231,12 +258,12 @@ def warm_service(service: AllocationService,
                 # avals must match exactly or dispatch misses the cache
                 obs_aval = _sds((Bp,), jnp.int32) if wo else None
                 obs64 = _sds((Bp,), jnp.int64) if wo else None
-                cells = [("policy", ("policy", Bp, wo, policy),
-                          make_policy_decide(policy, wo),
-                          (f64, f64, obs_aval), (0, 1, 2))]
+                cells.append(("policy", ("policy", Bp, wo, policy), Bp,
+                              make_policy_decide(policy, wo),
+                              (f64, f64, obs_aval), (0, 1, 2)))
                 if cfg.priced:
                     cells.append(
-                        ("priced", ("priced", Bp, wo, policy),
+                        ("priced", ("priced", Bp, wo, policy), Bp,
                          make_priced_decide(policy, wo),
                          (f64, f64, f64, obs_aval), (0, 1, 2, 3)))
                 if fused_ok:
@@ -247,17 +274,10 @@ def warm_service(service: AllocationService,
                     cells.append(
                         ("fused",
                          ("fused", service.model.cache_key, sig, wo, policy),
-                         make_fused_decide(service.model, policy, wo),
+                         Bp, make_fused_decide(service.model, policy, wo),
                          # fused converts observed *inside* enable_x64 -> i64
                          (service.model.params, padded, obs64), (1, 2)))
-                for kind, key, raw, avals, donate in cells:
-                    if key in service.replica.compiled:
-                        rep.n_already_cached += 1
-                        continue
-                    fn, rec = _aot_compile(raw, avals, donate, cfg, o,
-                                           kind, Bp)
-                    service.replica.install(key, fn)
-                    rep.add(rec)
+        _aot_compile_cells(cells, service.replica, cfg, o, rep)
     rep.cold_start_s = time.perf_counter() - t_wall
     return rep
 
@@ -278,17 +298,18 @@ def warm_fabric(fabric: ShardedAllocationService,
     t_wall = time.perf_counter()
     fused_ok = cfg.fused and fabric.model.supports_jit and template
     priced_opts = (False, True) if cfg.priced else (False,)
-    with o.tracer.span("aot.warmup", scope="fabric", K=K), enable_x64():
+    cells = []
+    with o.tracer.span("aot.warmup", scope="fabric", K=K), \
+            jax.enable_x64(True):
         for Bp in cfg.bucket_set(svc.batch_floor):
             f64 = _sds((K, Bp), jnp.float64)
             i64 = _sds((K, Bp), jnp.int64)
             for wo in cfg.observed:
-                cells = []
                 for pr in priced_opts:
                     cells.append(
                         (f"sharded_policy[{'priced' if pr else 'plain'}]",
                          ("sharded_policy", K, Bp, wo, pr, policy,
-                          fabric.mesh is not None),
+                          fabric.mesh is not None), Bp,
                          fabric._map_over_shards(
                              make_sharded_policy_per_shard(policy, wo, pr),
                              4, False),
@@ -301,19 +322,12 @@ def warm_fabric(fabric: ShardedAllocationService,
                     cells.append(
                         ("sharded_fused",
                          ("sharded_fused", K, fabric.model.cache_key, sig,
-                          wo, policy, fabric.mesh is not None),
+                          wo, policy, fabric.mesh is not None), Bp,
                          fabric._map_over_shards(
                              make_sharded_fused_per_shard(
                                  fabric.model, policy, wo), 2, True),
                          (fabric.model.params, stacked, i64), (1, 2)))
-                for kind, key, raw, avals, donate in cells:
-                    if key in svc.replica.compiled:
-                        rep.n_already_cached += 1
-                        continue
-                    fn, rec = _aot_compile(raw, avals, donate, cfg, o,
-                                           kind, Bp)
-                    svc.replica.install(key, fn)
-                    rep.add(rec)
+        _aot_compile_cells(cells, svc.replica, cfg, o, rep)
     rep.cold_start_s = time.perf_counter() - t_wall
     return rep
 

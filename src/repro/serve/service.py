@@ -7,7 +7,7 @@ trained ``PCCModel`` plus an ``AllocationPolicy`` become a batch function
                           -> choose_tokens_jnp -> tokens (B,)
 
 fused into a single jitted XLA executable per (model, input-shape bucket,
-policy). Decisions are computed in float64 (``enable_x64``) so they are
+policy). Decisions are computed in float64 (``jax.enable_x64``) so they are
 bitwise-equal to the numpy ``choose_tokens`` oracle run on the same decoded
 parameters. Host-only models (GBDT) predict (a, b) on the host and share
 the compiled policy stage.
@@ -37,7 +37,7 @@ puts N replicas of one trained model behind the same ``decide`` protocol:
 rows are stacked into one (K, Bp) block, and the fused
 features -> decode -> policy stage runs across every replica in a single
 compiled call — under ``jax.shard_map`` when the mesh really has one
-device per shard, falling back to ``vmap`` over the shard axis on 1-device
+device per shard, falling back to a loop over the shard axis on 1-device
 hosts. Per-shard blocks keep single-shard shapes, so decisions stay
 bitwise-equal to K independent single-shard services fed the same routed
 partitions (tests/test_alloc_parity.py).
@@ -51,8 +51,6 @@ from typing import Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.api._compat import warn_deprecated
@@ -431,7 +429,7 @@ class AllocationService:
         obs_j = None if obs_p is None else jnp.asarray(obs_p)
         if price is None:
             fn = self._policy_fn(Bp, obs is not None)
-            with enable_x64():
+            with jax.enable_x64(True):
                 toks, rt = fn(jnp.asarray(a64), jnp.asarray(b64), obs_j)
                 toks, rt = np.asarray(toks), np.asarray(rt)
             price_out = np.ones(B, np.float64)
@@ -439,7 +437,7 @@ class AllocationService:
             p64 = np.ones(Bp, np.float64)      # neutral price on padded rows
             p64[:B] = np.asarray(price, np.float64)
             fn = self._priced_fn(Bp, obs is not None)
-            with enable_x64():
+            with jax.enable_x64(True):
                 toks, rt = fn(jnp.asarray(a64), jnp.asarray(b64),
                               jnp.asarray(p64), obs_j)
                 toks, rt = np.asarray(toks), np.asarray(rt)
@@ -461,7 +459,7 @@ class AllocationService:
         # and their outputs are sliced off below
         obs_p = None if obs is None else pad_to(np.asarray(obs, np.int64), Bp)
         fn = self._fused_fn(self._shape_sig(padded), obs is not None)
-        with enable_x64():
+        with jax.enable_x64(True):
             toks, a, b, rt = fn(
                 self.model.params,
                 {k: jnp.asarray(v) for k, v in padded.items()},
@@ -525,8 +523,8 @@ class ShardedAllocationService:
     the batch bucket of the fullest shard — and one compiled call computes
     every replica's decisions. With a mesh that has one device per shard
     the per-shard stage runs under ``jax.shard_map`` (each device sees
-    exactly the single-shard shapes); on smaller hosts it falls back to
-    ``vmap`` over the shard axis. Either way the per-shard math is the
+    exactly the single-shard shapes); on smaller hosts it loops over the
+    shard axis (``jax.lax.map``). Either way the per-shard math is the
     single-shard math, so decisions are bitwise-equal to K independent
     ``AllocationService`` instances fed the routed partitions.
 
@@ -543,7 +541,7 @@ class ShardedAllocationService:
         self.n_shards = int(n_shards)
         self.replicas = [ReplicaState(k) for k in range(n_shards)]
         # shard_map needs exactly one device per shard; anything else (and
-        # in particular the 1-device smoke mesh) means vmap over the axis
+        # in particular the 1-device smoke mesh) means a loop over the axis
         self.mesh = (mesh if mesh is not None
                      and dict(mesh.shape).get("shard") == n_shards
                      and n_shards > 1 else None)
@@ -591,10 +589,15 @@ class ShardedAllocationService:
             specs = ((jax.tree.map(lambda _: P(), self.model.params),)
                      if with_params else ())
             specs += (P("shard"),) * n_args
-            return shard_map(block_fn, mesh=self.mesh, in_specs=specs,
-                             out_specs=P("shard"))
-        in_axes = ((None,) if with_params else ()) + (0,) * n_args
-        return jax.vmap(per_shard, in_axes=in_axes)
+            return jax.shard_map(block_fn, mesh=self.mesh, in_specs=specs,
+                                 out_specs=P("shard"))
+        # one device: a loop over shards, each at the single-shard shapes
+        # (``vmap`` would batch the model's matmuls, which changes their
+        # rounding)
+        if with_params:
+            return lambda params, *xs: jax.lax.map(
+                lambda x: per_shard(params, *x), xs)
+        return lambda *xs: jax.lax.map(lambda x: per_shard(*x), xs)
 
     def _sharded_policy_fn(self, Bp: int, with_observed: bool, priced: bool):
         key = ("sharded_policy", self.n_shards, Bp, with_observed, priced,
@@ -671,7 +674,7 @@ class ShardedAllocationService:
         obs2 = (np.zeros((self.n_shards, Bp), np.int64) if obs is None
                 else self._stack(shard_of, pos, Bp, obs, np.int64))
         fn = self._sharded_policy_fn(Bp, obs is not None, price is not None)
-        with enable_x64():
+        with jax.enable_x64(True):
             toks, rt = fn(jnp.asarray(a2), jnp.asarray(b2), jnp.asarray(p2),
                           jnp.asarray(obs2))
             toks, rt = np.asarray(toks), np.asarray(rt)
@@ -698,7 +701,7 @@ class ShardedAllocationService:
                 else self._stack(shard_of, pos, Bp, obs, np.int64))
         sig = tuple(sorted((k, v.shape) for k, v in stacked.items()))
         fn = self._sharded_fused_fn(sig, obs is not None)
-        with enable_x64():
+        with jax.enable_x64(True):
             toks, a, b, rt = fn(
                 self.model.params,
                 {k: jnp.asarray(v) for k, v in stacked.items()},

@@ -8,7 +8,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.experimental import enable_x64
 
 from repro.api import AllocationRequest, DecisionContext, Provenance
 from repro.cluster.router import Router
@@ -286,7 +285,7 @@ def test_service_policy_default_not_shared():
 @pytest.mark.parametrize("max_slowdown", [0.0, 0.05, 0.3])
 def test_min_tokens_within_slowdown_parity(max_slowdown):
     SMAX = 256
-    with enable_x64():
+    with jax.enable_x64(True):
         fn = jax.jit(jax.vmap(min_tokens_within_slowdown_jnp,
                               in_axes=(0, 0, 0, None)),
                      static_argnums=3)

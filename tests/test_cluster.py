@@ -121,6 +121,26 @@ def test_pool_shards_cross_shard_expiry_and_resize():
                            np.array([1.0]))
 
 
+def test_fused_admission_keeps_a_lease_ending_within_f32_of_now():
+    """The simulator's fused admission (impl="jnp") keeps float64 lease end
+    times: a lease ending 1e-5 s after ``now`` — the same f32 value — is
+    still live, so the next admission takes another slot and the host
+    mirror, the device tables and the token count stay in agreement."""
+    pool = PoolShards(capacity_per_shard=100, n_shards=1, max_leases=8)
+    head = lambda v: np.array([[v] + [0] * 7])
+    ends = lambda v: np.array([[v] + [0.0] * 7])
+    assert np.float32(1000.00002) == np.float32(1000.00001)
+    pool.admit_epoch(0.0, head(7), head(10), ends(1000.00002), impl="jnp")
+    pool.expire(1000.00001)
+    pool.admit_epoch(1000.00001, head(8), head(5), ends(2000.0), impl="jnp")
+    assert pool._query[0, :2].tolist() == [7, 8]
+    assert pool._tokens[0].sum() == pool.in_use[0] == 15
+    np.testing.assert_array_equal(np.asarray(pool.device_tables[1]),
+                                  pool._tokens)
+    np.testing.assert_array_equal(np.asarray(pool.device_tables[0]),
+                                  pool._end_s)
+
+
 # ------------------------------------------------------------------- router --
 def test_router_seeded_contracts():
     """Seeded twin of the hypothesis sweep (tests/test_router.py), so the
@@ -631,3 +651,20 @@ def test_fused_replay_conserves_and_reports_roofline():
     assert rep2.n_admitted == rep.n_admitted
     assert rep2.n_epochs == rep.n_epochs
     assert rep2.mean_utilization == rep.mean_utilization
+
+
+def test_fused_replay_pallas_body_matches_jnp_twin():
+    """The replay on the Pallas epoch body (interpreted here; compiled on a
+    TPU), whose f32/i32 tables feed the next launch, counts what the
+    float64 jnp twin counts: whole-second times are exact in f32."""
+    from repro.cluster import FusedReplay, ReplayConfig
+    stream = TraceGenerator(seed=71, n_unique=16, rate_qps=2.0).stream(
+        600, chunk_size=256).buffer()
+    reps = [FusedReplay(ReplayConfig(capacity=8192, n_shards=2,
+                                     max_leases=256, epoch_s=60.0,
+                                     queue_block=128, impl=impl)).run(stream)
+            for impl in ("interpret", "jnp")]
+    for f in ("n_admitted", "n_completed", "n_rejected", "n_epochs",
+              "launches", "mean_utilization"):
+        assert getattr(reps[0], f) == getattr(reps[1], f), f
+    assert reps[0].n_completed == reps[0].n_admitted > 0

@@ -17,8 +17,8 @@ expire-before-admit ordering hold for every generated epoch.
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
-from jax.experimental import enable_x64
 
 from repro.core.allocator import AllocationPolicy, choose_tokens_priced
 from repro.core.arepas import simulate_runtime
@@ -93,7 +93,7 @@ def _assert_conserved(tokens, out):
 
 def test_epoch_ref_matches_sequential_oracle():
     rng = np.random.default_rng(0)
-    with enable_x64():
+    with jax.enable_x64(True):
         for trial in range(12):
             case = _random_epoch(rng, K=int(rng.integers(1, 5)),
                                  L=int(rng.choice([8, 16, 32])),
@@ -143,7 +143,7 @@ def test_slot_exhaustion_caps_admission_without_leaking_tokens():
     free = np.array([10_000], np.int64)        # tokens are NOT the bound
     q_tok = np.full((K, Q), 3, np.int64)
     q_end = np.full((K, Q), 500.0)
-    with enable_x64():
+    with jax.enable_x64(True):
         out = epoch_step_ref(jnp.asarray(end_s, jnp.float64),
                              jnp.asarray(tokens), jnp.asarray(free),
                              jnp.asarray(q_tok), jnp.asarray(q_end),
@@ -175,7 +175,7 @@ def test_resize_ref_matches_scalar_oracle():
     cand_tok = rng.integers(8, 200, C).astype(np.float64)
     cand_end = rng.uniform(100, 400, C)
     now, epoch_s = 50.0, 8.0
-    with enable_x64():
+    with jax.enable_x64(True):
         tgt, sel, rt, new_end = resize_step_ref(
             jnp.asarray(a), jnp.asarray(b), jnp.asarray(price),
             jnp.asarray(obs), jnp.asarray(floor), jnp.asarray(done),

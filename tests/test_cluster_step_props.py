@@ -8,8 +8,8 @@ absent (see requirements.txt), like tests/test_scheduler_props.py.
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
-from jax.experimental import enable_x64
 
 from repro.kernels.cluster_step import epoch_step_ref
 
@@ -49,7 +49,7 @@ def epoch_cases(draw):
 @given(epoch_cases())
 def test_epoch_properties(case):
     end_s, tokens, free, q_tok, q_end, now = case
-    with enable_x64():
+    with jax.enable_x64(True):
         out = epoch_step_ref(jnp.asarray(end_s, jnp.float64),
                              jnp.asarray(tokens), jnp.asarray(free),
                              jnp.asarray(q_tok), jnp.asarray(q_end),

@@ -223,6 +223,33 @@ def _assert_trace_event_schema(events):
         last_ts[key] = e["ts"]
 
 
+def test_device_profile_raises_when_the_profiler_cannot_start(tmp_path):
+    """A trace that was asked for and cannot be taken is an error, not a
+    silently missing file: here the profiler is already running."""
+    import jax
+    from repro.obs import device_profile
+    jax.profiler.start_trace(str(tmp_path / "outer"))
+    try:
+        ran = []
+        with pytest.raises(RuntimeError, match="already been started"):
+            with device_profile(str(tmp_path / "inner")):
+                ran.append(True)
+        assert not ran
+    finally:
+        jax.profiler.stop_trace()
+
+
+@pytest.mark.parametrize("log_dir", [None, "profile"])
+def test_device_profile_propagates_body_exceptions(tmp_path, log_dir):
+    from repro.obs import device_profile
+    path = None if log_dir is None else str(tmp_path / log_dir)
+    with pytest.raises(KeyError, match="boom"):
+        with device_profile(path):
+            raise KeyError("boom")
+    if path is not None:          # the capture was still closed and written
+        assert list((tmp_path / log_dir).rglob("*.xplane.pb"))
+
+
 def test_perfetto_export_schema_and_tracks(tmp_path):
     clk = FakeClock()
     tr = Tracer(clock=clk)

@@ -68,7 +68,6 @@ def test_int8_compression_roundtrip():
 
 def test_compressed_psum_error_feedback():
     """Error feedback: quantization residual carried, not lost."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
     mesh = Mesh(np.array(jax.devices()[:1]), ("pod",))
     g = jax.random.normal(jax.random.PRNGKey(1), (64,))
@@ -77,8 +76,8 @@ def test_compressed_psum_error_feedback():
     def f(g, r):
         return compressed_psum(g, r, "pod")
 
-    out, new_res = shard_map(f, mesh=mesh, in_specs=(P(), P()),
-                             out_specs=(P(), P()))(g, res)
+    out, new_res = jax.shard_map(f, mesh=mesh, in_specs=(P(), P()),
+                                 out_specs=(P(), P()))(g, res)
     # single participant: mean == dequantized value; residual = quant error
     np.testing.assert_allclose(np.asarray(out + new_res), np.asarray(g),
                                atol=1e-5)
