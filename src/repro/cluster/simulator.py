@@ -120,8 +120,8 @@ class StreamingArrivals:
     arrival <= now are provably all delivered once an event beyond ``now``
     (or exhaustion) has been seen — ``take_until`` pulls chunks exactly
     until then and holds the overshoot for the next epoch. A full backlog
-    blocks the producer (backpressure), never drops events; the depth gauge
-    and saturation counter come with the Backlog.
+    blocks the producer (backpressure), never drops events; the saturation
+    counter comes with the Backlog.
     """
 
     def __init__(self, arrival: np.ndarray, backlog: int = 1024,

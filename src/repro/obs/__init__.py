@@ -3,8 +3,9 @@
 One ``Obs`` bundle threads through every serving layer (service, batcher,
 frontend, router, simulator, fused replay):
 
-  * ``tracer``   — request-lifecycle span tracing with an injectable clock
-    and a fixed-size ring buffer (``obs.trace``);
+  * ``tracer``   — request-lifecycle span tracing with an injectable clock,
+    per-thread rings of rows, and every span mirrored into the JAX
+    profiler's trace (``obs.trace``);
   * ``metrics``  — counters / gauges / log-bucketed latency histograms
     that merge across the K shards (``obs.metrics``);
   * ``recorder`` — sampled ``AllocationRequest -> AllocationDecision``
@@ -14,9 +15,17 @@ frontend, router, simulator, fused replay):
 
 The plane is *always on*: every seam calls into its ``Obs`` bundle
 unconditionally, and ``NULL_OBS`` (the default everywhere) resolves every
-call to a shared no-op — the disabled path is gated at ~0% overhead and a
-traced replay is decision-identical to an untraced one (the
-``obs_overhead`` benchmark and tests/test_obs.py).
+call to a shared no-op. What the served path costs on the chip with
+tracing off is measured by the chip benchmark and kept in its ledger
+(``PERF_LEDGER.jsonl``); a traced replay is decision-identical to an
+untraced one (tests/test_obs.py).
+
+The served path (``repro.serve.plane``, ``repro.serve.service``) records
+``backlog.put`` / ``backlog.get`` points by request id, ``plane.idle`` and
+``plane.batch`` spans, ``frontend.submit`` and ``microbatch.flush`` in the
+micro-batcher, ``service.decide`` split into ``decide.dispatch`` and
+``decide.download`` (``transfers``), and ``python.gc`` spans while a plane
+with a recording tracer runs (``obs.trace`` lists what each measures).
 
 The streaming AOT serving plane adds its own instrument family (all
 created dynamically — instruments exist the first time a layer touches
@@ -26,9 +35,9 @@ them):
     pass, with one ``aot.compile`` point per pinned executable;
   * ``decision_cold_start_s`` histogram — per-executable lower+compile+warm
     cost, plus ``aot_precompiled`` / ``aot_cold_start_s`` stack totals;
-  * ``backlog_depth`` gauge and ``backlog_saturations`` counter on the
-    bounded admission queue (``repro.serve.plane.Backlog``) — a saturated
-    plane is visible in metrics, not just in producer latency;
+  * ``backlog_saturations`` counter on the bounded admission queue
+    (``repro.serve.plane.Backlog``) — a saturated plane is visible in
+    metrics, not just in producer latency;
   * ``decision_compile_s`` vs ``decision_latency_s`` split is per-thread
     under the plane's workers (``ReplicaState`` compile-stall tracking), so
     the SLO-gated latency series never mixes in another thread's compile.

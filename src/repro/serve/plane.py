@@ -23,12 +23,13 @@ in ``ReplicaState``'s locked cache/counters. Submissions return
 """
 from __future__ import annotations
 
+import gc
 import itertools
 import os
 import queue
 import threading
 from concurrent.futures import Future
-from typing import Dict, List, Optional, Sequence
+from typing import Callable, Dict, List, Optional, Sequence
 
 import numpy as np
 
@@ -46,15 +47,19 @@ class Backlog:
     A full backlog blocks the producing ``put`` until a worker drains a
     slot — arrivals beyond service capacity slow the producer down rather
     than accumulate without bound. Every saturation event is counted
-    (``backlog_saturations``) and the depth is exported as a gauge
-    (``backlog_depth``) on both enqueue and dequeue, so a saturated plane
-    is visible in the metrics, not just in producer latency.
+    (``backlog_saturations``), so a saturated plane is visible in the
+    metrics, not just in producer latency. Where ``key`` gives each item
+    an id, a ``backlog.put`` point (recorded before the item is queued)
+    and a ``backlog.get`` point carry it: each item's wait, and the depth
+    at any instant, can be read from the trace.
     """
 
-    def __init__(self, capacity: int = 1024, obs: Optional[Obs] = None):
+    def __init__(self, capacity: int = 1024, obs: Optional[Obs] = None,
+                 key: Optional[Callable] = None):
         assert capacity >= 1
         self.capacity = int(capacity)
         self.obs = NULL_OBS if obs is None else obs
+        self._key = key
         self._q: "queue.Queue" = queue.Queue(maxsize=self.capacity)
         self._saturations = 0
 
@@ -66,7 +71,12 @@ class Backlog:
         """Times a ``put`` found the queue full (producer backpressured)."""
         return self._saturations
 
+    def _note(self, name: str, item) -> None:
+        if self._key is not None:
+            self.obs.tracer.point(name, id=self._key(item))
+
     def put(self, item, block: bool = True) -> None:
+        self._note("backlog.put", item)
         try:
             self._q.put_nowait(item)
         except queue.Full:
@@ -75,17 +85,20 @@ class Backlog:
             if not block:
                 raise
             self._q.put(item)            # backpressure: block the producer
-        self.obs.metrics.gauge("backlog_depth").set(self._q.qsize())
 
     def get(self, timeout: Optional[float] = None):
         item = self._q.get(timeout=timeout)
-        self.obs.metrics.gauge("backlog_depth").set(self._q.qsize())
+        self._note("backlog.get", item)
         return item
 
     def get_nowait(self):
         item = self._q.get_nowait()
-        self.obs.metrics.gauge("backlog_depth").set(self._q.qsize())
+        self._note("backlog.get", item)
         return item
+
+
+def _request_id(item) -> int:
+    return item[0].request_id
 
 
 class ServingPlane:
@@ -114,8 +127,9 @@ class ServingPlane:
         self.max_batch = int(max_batch)
         self.node_cap = node_cap
         self.pin_workers = bool(pin_workers)
-        self.backlog = Backlog(backlog, obs=self.obs)
+        self.backlog = Backlog(backlog, obs=self.obs, key=_request_id)
         self.warmup_report: Optional[WarmupReport] = None
+        self._gc_hook = None
         self._ids = itertools.count()
         self._id_lock = threading.Lock()
         self._stopping = threading.Event()
@@ -146,6 +160,9 @@ class ServingPlane:
             self.warmup_report = warm_allocation_stack(
                 self.service, None, jobs=warm_jobs, cfg=cfg, obs=self.obs)
         self._stopping.clear()
+        if self.obs.tracer.enabled:
+            self._gc_hook = self.obs.tracer.on_gc
+            gc.callbacks.append(self._gc_hook)
         for i in range(self.n_workers):
             t = threading.Thread(target=self._worker, args=(i,),
                                  name=f"serving-plane-{i}", daemon=True)
@@ -167,6 +184,9 @@ class ServingPlane:
         for t in self._threads:
             t.join()
         self._threads = []
+        if self._gc_hook is not None:
+            gc.callbacks.remove(self._gc_hook)
+            self._gc_hook = None
 
     def __enter__(self) -> "ServingPlane":
         if not self._threads:
@@ -218,30 +238,37 @@ class ServingPlane:
         batcher = MicroBatcher(self.service, max_batch=self.max_batch,
                                obs=self.obs, node_cap=self.node_cap)
         futures: Dict[int, Future] = {}
+        tracer = self.obs.tracer
         while True:
             try:
-                item = self.backlog.get(timeout=self.IDLE_POLL_S)
+                with tracer.span("plane.idle"):
+                    item = self.backlog.get(timeout=self.IDLE_POLL_S)
             except queue.Empty:
                 if self._stopping.is_set():
                     return
                 continue
-            # hot-swap pickup: a new batch decides on the plane's current
-            # service; the batch already flushing finished on the old one
-            if batcher.service is not self.service:
-                batcher.service = self.service
-            req, fut = item
-            batcher.submit(req)
-            futures[req.request_id] = fut
-            # opportunistically drain without blocking: whatever is already
-            # queued rides in this batch, up to the batcher's chunk size
-            while len(batcher) < self.max_batch:
-                try:
-                    req, fut = self.backlog.get_nowait()
-                except queue.Empty:
-                    break
+            with tracer.span("plane.batch") as span:
+                # hot-swap pickup: a new batch decides on the plane's
+                # current service; the batch already flushing finished on
+                # the old one
+                if batcher.service is not self.service:
+                    batcher.service = self.service
+                req, fut = item
                 batcher.submit(req)
                 futures[req.request_id] = fut
-            self._flush(batcher, futures)
+                # opportunistically drain without blocking: whatever is
+                # already queued rides in this batch, up to the batcher's
+                # chunk size
+                while len(batcher) < self.max_batch:
+                    try:
+                        req, fut = self.backlog.get_nowait()
+                    except queue.Empty:
+                        break
+                    batcher.submit(req)
+                    futures[req.request_id] = fut
+                if span is not None:
+                    span.attrs["n"] = len(futures)
+                self._flush(batcher, futures)
 
     def _flush(self, batcher: MicroBatcher, futures: Dict[int, Future]
                ) -> None:

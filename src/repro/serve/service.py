@@ -129,6 +129,14 @@ def _protocol_dispatch(engine, request: AllocationRequest,
     return d
 
 
+def _download(tracer, outputs) -> List[np.ndarray]:
+    """Every output of a compiled decide call on the host, under one
+    ``decide.download`` span: the wait for the device, then one transfer
+    per output."""
+    with tracer.span("decide.download", transfers=len(outputs)):
+        return [np.asarray(x) for x in outputs]
+
+
 def _observed_dispatch(engine, span_name: str, request: AllocationRequest,
                        ctx: DecisionContext, decide_params, decide_fused,
                        **span_attrs) -> AllocationDecision:
@@ -422,26 +430,28 @@ class AllocationService:
         a = np.asarray(a)
         B = a.shape[0]
         self.replica.count(calls=1, queries=B)
-        Bp = batch_bucket(B, self.batch_floor)
-        a64 = pad_to(np.asarray(a, np.float64), Bp)
-        b64 = pad_to(np.asarray(b, np.float64), Bp)
-        obs_p = None if obs is None else pad_to(np.asarray(obs, np.int64), Bp)
-        obs_j = None if obs_p is None else jnp.asarray(obs_p)
-        if price is None:
-            fn = self._policy_fn(Bp, obs is not None)
-            with jax.enable_x64(True):
-                toks, rt = fn(jnp.asarray(a64), jnp.asarray(b64), obs_j)
-                toks, rt = np.asarray(toks), np.asarray(rt)
-            price_out = np.ones(B, np.float64)
-        else:
-            p64 = np.ones(Bp, np.float64)      # neutral price on padded rows
-            p64[:B] = np.asarray(price, np.float64)
-            fn = self._priced_fn(Bp, obs is not None)
-            with jax.enable_x64(True):
-                toks, rt = fn(jnp.asarray(a64), jnp.asarray(b64),
-                              jnp.asarray(p64), obs_j)
-                toks, rt = np.asarray(toks), np.asarray(rt)
-            price_out = np.asarray(price, np.float64)
+        tracer = self.obs.tracer
+        with tracer.span("decide.dispatch"):
+            Bp = batch_bucket(B, self.batch_floor)
+            a64 = pad_to(np.asarray(a, np.float64), Bp)
+            b64 = pad_to(np.asarray(b, np.float64), Bp)
+            obs_p = (None if obs is None
+                     else pad_to(np.asarray(obs, np.int64), Bp))
+            obs_j = None if obs_p is None else jnp.asarray(obs_p)
+            if price is None:
+                fn = self._policy_fn(Bp, obs is not None)
+                with jax.enable_x64(True):
+                    out = fn(jnp.asarray(a64), jnp.asarray(b64), obs_j)
+                price_out = np.ones(B, np.float64)
+            else:
+                p64 = np.ones(Bp, np.float64)  # neutral price on padded rows
+                p64[:B] = np.asarray(price, np.float64)
+                fn = self._priced_fn(Bp, obs is not None)
+                with jax.enable_x64(True):
+                    out = fn(jnp.asarray(a64), jnp.asarray(b64),
+                             jnp.asarray(p64), obs_j)
+                price_out = np.asarray(price, np.float64)
+        toks, rt = _download(tracer, out)
         toks, rt = toks[:B], rt[:B]
         return AllocationDecision(
             tokens=toks, runtime=rt, a=a, b=np.asarray(b),
@@ -453,19 +463,21 @@ class AllocationService:
                       obs: Optional[np.ndarray]) -> AllocationDecision:
         B = next(iter(model_in.values())).shape[0]
         self.replica.count(calls=1, queries=B)
-        Bp = batch_bucket(B, self.batch_floor)
-        padded = {k: pad_to(np.asarray(v), Bp) for k, v in model_in.items()}
-        # zero-padded observed rows are harmless: the bisection degenerates
-        # and their outputs are sliced off below
-        obs_p = None if obs is None else pad_to(np.asarray(obs, np.int64), Bp)
-        fn = self._fused_fn(self._shape_sig(padded), obs is not None)
-        with jax.enable_x64(True):
-            toks, a, b, rt = fn(
-                self.model.params,
-                {k: jnp.asarray(v) for k, v in padded.items()},
-                None if obs_p is None else jnp.asarray(obs_p))
-            toks, a, b, rt = (np.asarray(toks), np.asarray(a),
-                              np.asarray(b), np.asarray(rt))
+        tracer = self.obs.tracer
+        with tracer.span("decide.dispatch"):
+            Bp = batch_bucket(B, self.batch_floor)
+            padded = {k: pad_to(np.asarray(v), Bp)
+                      for k, v in model_in.items()}
+            # zero-padded observed rows are harmless: the bisection
+            # degenerates and their outputs are sliced off below
+            obs_p = (None if obs is None
+                     else pad_to(np.asarray(obs, np.int64), Bp))
+            fn = self._fused_fn(self._shape_sig(padded), obs is not None)
+            with jax.enable_x64(True):
+                out = fn(self.model.params,
+                         {k: jnp.asarray(v) for k, v in padded.items()},
+                         None if obs_p is None else jnp.asarray(obs_p))
+        toks, a, b, rt = _download(tracer, out)
         toks, rt = toks[:B], rt[:B]
         return AllocationDecision(
             tokens=toks, runtime=rt, a=a[:B], b=b[:B],
@@ -666,18 +678,22 @@ class ShardedAllocationService:
                        obs: Optional[np.ndarray]) -> AllocationDecision:
         a = np.asarray(a)
         B = a.shape[0]
-        shard_of, pos, Bp = self._place(shard_of)
-        a2 = self._stack(shard_of, pos, Bp, a, np.float64)
-        b2 = self._stack(shard_of, pos, Bp, b, np.float64)
-        p2 = (np.ones((self.n_shards, Bp), np.float64) if price is None
-              else self._stack(shard_of, pos, Bp, price, np.float64, fill=1))
-        obs2 = (np.zeros((self.n_shards, Bp), np.int64) if obs is None
-                else self._stack(shard_of, pos, Bp, obs, np.int64))
-        fn = self._sharded_policy_fn(Bp, obs is not None, price is not None)
-        with jax.enable_x64(True):
-            toks, rt = fn(jnp.asarray(a2), jnp.asarray(b2), jnp.asarray(p2),
-                          jnp.asarray(obs2))
-            toks, rt = np.asarray(toks), np.asarray(rt)
+        tracer = self.obs.tracer
+        with tracer.span("decide.dispatch"):
+            shard_of, pos, Bp = self._place(shard_of)
+            a2 = self._stack(shard_of, pos, Bp, a, np.float64)
+            b2 = self._stack(shard_of, pos, Bp, b, np.float64)
+            p2 = (np.ones((self.n_shards, Bp), np.float64) if price is None
+                  else self._stack(shard_of, pos, Bp, price, np.float64,
+                                   fill=1))
+            obs2 = (np.zeros((self.n_shards, Bp), np.int64) if obs is None
+                    else self._stack(shard_of, pos, Bp, obs, np.int64))
+            fn = self._sharded_policy_fn(Bp, obs is not None,
+                                         price is not None)
+            with jax.enable_x64(True):
+                out = fn(jnp.asarray(a2), jnp.asarray(b2), jnp.asarray(p2),
+                         jnp.asarray(obs2))
+        toks, rt = _download(tracer, out)
         toks, rt = toks[shard_of, pos], rt[shard_of, pos]
         return AllocationDecision(
             tokens=toks, runtime=rt, a=a, b=np.asarray(b),
@@ -694,20 +710,21 @@ class ShardedAllocationService:
         across all K replicas in one compiled call, unstack to input
         order."""
         B = next(iter(model_in.values())).shape[0]
-        shard_of, pos, Bp = self._place(shard_of)
-        stacked = {k: self._stack(shard_of, pos, Bp, v, np.asarray(v).dtype)
-                   for k, v in model_in.items()}
-        obs2 = (np.zeros((self.n_shards, Bp), np.int64) if obs is None
-                else self._stack(shard_of, pos, Bp, obs, np.int64))
-        sig = tuple(sorted((k, v.shape) for k, v in stacked.items()))
-        fn = self._sharded_fused_fn(sig, obs is not None)
-        with jax.enable_x64(True):
-            toks, a, b, rt = fn(
-                self.model.params,
-                {k: jnp.asarray(v) for k, v in stacked.items()},
-                jnp.asarray(obs2))
-            toks, a, b, rt = (np.asarray(toks), np.asarray(a),
-                              np.asarray(b), np.asarray(rt))
+        tracer = self.obs.tracer
+        with tracer.span("decide.dispatch"):
+            shard_of, pos, Bp = self._place(shard_of)
+            stacked = {k: self._stack(shard_of, pos, Bp, v,
+                                      np.asarray(v).dtype)
+                       for k, v in model_in.items()}
+            obs2 = (np.zeros((self.n_shards, Bp), np.int64) if obs is None
+                    else self._stack(shard_of, pos, Bp, obs, np.int64))
+            sig = tuple(sorted((k, v.shape) for k, v in stacked.items()))
+            fn = self._sharded_fused_fn(sig, obs is not None)
+            with jax.enable_x64(True):
+                out = fn(self.model.params,
+                         {k: jnp.asarray(v) for k, v in stacked.items()},
+                         jnp.asarray(obs2))
+        toks, a, b, rt = _download(tracer, out)
         toks, rt = toks[shard_of, pos], rt[shard_of, pos]
         return AllocationDecision(
             tokens=toks, runtime=rt, a=a[shard_of, pos], b=b[shard_of, pos],

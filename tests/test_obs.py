@@ -8,6 +8,7 @@ seam calls into an Obs bundle), so a recording bundle must observe without
 perturbing — a seeded replay's ClusterReport has to come out equal whether
 the installed tracer records or no-ops.
 """
+import gc
 import json
 
 import numpy as np
@@ -20,7 +21,8 @@ from repro.core.models import NNConfig
 from repro.core.pipeline import TasqConfig, TasqPipeline
 from repro.obs import (NULL_OBS, FlightRecorder, Histogram, MetricsRegistry,
                        Obs, Tracer, trace_events, write_trace)
-from repro.serve import MicroBatcher
+from repro.serve import MicroBatcher, ServingPlane, WarmupConfig
+from repro.serve.aot import model_pool_inputs
 from repro.serve.service import AllocationService
 from repro.workloads import TraceGenerator
 
@@ -205,6 +207,66 @@ def test_traced_replay_is_decision_identical(service, trace, tmp_path):
     doc = json.loads((tmp_path / "replay.json").read_text())
     assert doc["traceEvents"] and len(doc["traceEvents"]) == n
     _assert_trace_event_schema(doc["traceEvents"])
+
+
+# ------------------------------------------------------ the served path ----
+def _serve(service, trace, obs, n: int):
+    """Send ``n`` single-query requests through a two-worker plane over
+    ``service``'s model with ``obs`` installed; a full collection runs
+    while the plane is up. Returns (gc.callbacks while it ran, after)."""
+    svc = AllocationService(service.model, service.policy, obs=obs)
+    pool = model_pool_inputs(svc.model, trace.jobs)
+    plane = ServingPlane(svc, n_workers=2, max_batch=8, backlog=64)
+    plane.start(warm_jobs=trace.jobs,
+                warmup=WarmupConfig(buckets=(8,), observed=(True, False)))
+    hooks = list(gc.callbacks)
+    obs.tracer.clear()
+    futs = [plane.submit({k: v[i % v.shape[0]] for k, v in pool.items()},
+                         observed_tokens=None if i % 3 else 40 + i)
+            for i in range(n)]
+    assert all(f.result(timeout=60) >= 1 for f in futs)
+    gc.collect()
+    plane.stop()
+    return hooks, list(gc.callbacks)
+
+
+def test_the_served_path_records_ids_downloads_and_collections(service,
+                                                               trace):
+    obs = Obs.enabled()
+    before = list(gc.callbacks)
+    hooks, after = _serve(service, trace, obs, 40)
+    assert len(hooks) == len(before) + 1 and after == before
+    recs = obs.tracer.records()
+    puts = {r.attrs["id"]: r for r in recs if r.name == "backlog.put"}
+    gets = {r.attrs["id"]: r for r in recs if r.name == "backlog.get"}
+    assert set(puts) == set(gets) == set(range(40))
+    assert all(puts[i].t0 <= gets[i].t0 for i in puts)
+    assert {gets[i].thread for i in gets} != {puts[0].thread}
+    batches = [r for r in recs if r.name == "plane.batch"]
+    assert sum(b.attrs["n"] for b in batches) == 40
+    decides = [r for r in recs if r.name == "service.decide"]
+    downloads = {r.parent: r for r in recs if r.name == "decide.download"}
+    dispatches = {r.parent: r for r in recs if r.name == "decide.dispatch"}
+    assert decides and len(downloads) == len(decides)
+    by_id = {r.span_id: r for r in recs if r.kind == "span"}
+    for d in decides:
+        down, disp = downloads[d.span_id], dispatches[d.span_id]
+        assert down.attrs == {"transfers": 4}
+        assert d.t0 <= disp.t0 <= disp.t1 <= down.t0 <= down.t1 <= d.t1
+        assert down.thread == d.thread == by_id[
+            by_id[d.parent].parent].thread      # flush, then the batch
+        assert by_id[by_id[d.parent].parent].name == "plane.batch"
+    assert any(r.name == "plane.idle" for r in recs)
+    gcs = [r for r in recs if r.name == "python.gc"]
+    assert 2 in {r.attrs["generation"] for r in gcs}
+
+
+def test_the_null_plane_records_nothing_on_the_served_path(service, trace):
+    before = list(gc.callbacks)
+    hooks, after = _serve(service, trace, NULL_OBS, 12)
+    assert hooks == before and after == before
+    assert NULL_OBS.tracer.records() == [] and NULL_OBS.tracer.dropped == 0
+    assert NULL_OBS.metrics.names() == []
 
 
 # --------------------------------------------------------- perfetto export --
