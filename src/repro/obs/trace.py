@@ -41,7 +41,7 @@ The served path (``repro.serve``) records these names:
     micro-batcher;
   * ``service.decide`` span, and inside it ``decide.dispatch`` (pad,
     host-to-device, launch) and ``decide.download`` (``transfers``: the
-    device wait and every output brought to the host);
+    device wait and the call's one packed output brought to the host);
   * ``python.gc`` spans, while a plane with a recording tracer runs.
 
 Records are plain host-side rows; nothing here changes device state or

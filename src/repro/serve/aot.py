@@ -6,8 +6,9 @@ query's tail latency. This module moves every one of those compiles to
 startup: enumerate the (engine, batch-bucket, priced, observed) grid the
 stack can serve, ``jax.jit(...).lower(...).compile()`` each executable
 (the ``launch/dryrun.py`` lower/compile pattern), warm it with one dummy
-invocation so first-touch runtime costs (program load, allocator warmup)
-are paid too, and pin the result into ``ReplicaState.compiled`` at the
+invocation — whose one packed output comes to the host in one transfer —
+so first-touch runtime costs (program load, allocator warmup) are paid
+too, and pin the result into ``ReplicaState.compiled`` at the
 exact key the lazy builder would have used — the hot path then finds every
 key present and never traces (``stats["compiles"] == 0``).
 
@@ -42,7 +43,6 @@ from repro.obs import NULL_OBS, Obs
 from repro.serve.service import (AllocationService, ShardedAllocationService,
                                  make_fused_decide, make_policy_decide,
                                  make_priced_decide,
-                                 make_sharded_fused_per_shard,
                                  make_sharded_policy_per_shard)
 
 __all__ = ["WarmupConfig", "WarmupReport", "ExecutableRecord",
@@ -324,8 +324,8 @@ def warm_fabric(fabric: ShardedAllocationService,
                          ("sharded_fused", K, fabric.model.cache_key, sig,
                           wo, policy, fabric.mesh is not None), Bp,
                          fabric._map_over_shards(
-                             make_sharded_fused_per_shard(
-                                 fabric.model, policy, wo), 2, True),
+                             make_fused_decide(fabric.model, policy, wo),
+                             2, True),
                          (fabric.model.params, stacked, i64), (1, 2)))
         _aot_compile_cells(cells, svc.replica, cfg, o, rep)
     rep.cold_start_s = time.perf_counter() - t_wall

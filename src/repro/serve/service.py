@@ -16,6 +16,14 @@ Compiled functions are cached on (model.cache_key, shape signature,
 observed?, policy); ``stats["compiles"]`` exposes cache behavior to tests
 and benchmarks.
 
+Each compiled decide function returns one device array: its outputs
+(tokens, runtime; plus a, b on the fused path) stacked as the rows of a
+float64 array, each exact there. ``_download`` brings that array to the
+host in one transfer and casts each row back to the dtype a static
+layout gives it (``POLICY_LAYOUT``; ``AllocationService.fused_layout``,
+fixed by the model), so every decide call costs one device-to-host
+transfer whatever its path.
+
 Typed protocol (PR 5): the one entry point is
 
     decide(AllocationRequest, DecisionContext) -> AllocationDecision
@@ -45,6 +53,7 @@ partitions (tests/test_alloc_parity.py).
 from __future__ import annotations
 
 import dataclasses
+import functools
 import threading
 from typing import Dict, List, Optional, Tuple
 
@@ -64,7 +73,7 @@ from repro.serve.batching import batch_bucket, pad_to, shard_positions
 __all__ = ["AllocationResult", "AllocationService", "ReplicaState",
            "ShardedAllocationService", "make_fused_decide",
            "make_policy_decide", "make_priced_decide",
-           "make_sharded_fused_per_shard", "make_sharded_policy_per_shard"]
+           "make_sharded_policy_per_shard"]
 
 
 @dataclasses.dataclass
@@ -129,12 +138,16 @@ def _protocol_dispatch(engine, request: AllocationRequest,
     return d
 
 
-def _download(tracer, outputs) -> List[np.ndarray]:
-    """Every output of a compiled decide call on the host, under one
-    ``decide.download`` span: the wait for the device, then one transfer
-    per output."""
-    with tracer.span("decide.download", transfers=len(outputs)):
-        return [np.asarray(x) for x in outputs]
+def _download(tracer, packed, layout) -> List[np.ndarray]:
+    """A compiled decide call's packed output on the host, in one transfer
+    under the ``decide.download`` span (the wait for the device included),
+    split into its rows: row ``i`` of the (..., R, Bp) array comes back as
+    ``layout[i]``, by a static layout (``POLICY_LAYOUT``,
+    ``AllocationService.fused_layout``)."""
+    with tracer.span("decide.download", transfers=1):
+        host = np.asarray(packed)
+        return [host[..., i, :].astype(dt, copy=False)
+                for i, dt in enumerate(layout)]
 
 
 def _observed_dispatch(engine, span_name: str, request: AllocationRequest,
@@ -183,37 +196,66 @@ def _observed_dispatch(engine, span_name: str, request: AllocationRequest,
 # below wrap them in ``jax.jit`` on first request; the AOT warmup
 # (``repro.serve.aot``) lowers and compiles the *same* functions at startup
 # — one definition, so the two paths are bitwise-identical by construction.
+#
+# Each returns its outputs packed as the rows of one float64 array — (2, Bp)
+# for the policy stages, (4, Bp) for the fused stage, with a leading K on
+# the sharded paths — so a call's results reach the host in one transfer
+# (``_download``). Every row is exact in float64: tokens are integers far
+# below 2**53 and (a, b) are widened from the model's float. The static
+# layouts (``POLICY_LAYOUT``, ``AllocationService.fused_layout``) give each
+# row's dtype back.
+
+POLICY_LAYOUT = (np.int64, np.float64)                # tokens, runtime
+
+
+def decode_dtype(model) -> np.dtype:
+    """The float dtype a jit model decodes (a, b) to: its parameters'."""
+    return np.result_type(*(leaf.dtype
+                            for leaf in jax.tree.leaves(model.params)))
+
+
+def _pack(*rows):
+    return jnp.stack([r.astype(jnp.float64) for r in rows])
+
+
+def _policy_rows(a, b, policy: AllocationPolicy, price, observed):
+    toks = (choose_tokens_jnp(a, b, policy, observed) if price is None
+            else choose_tokens_priced_jnp(a, b, policy, price, observed))
+    return _pack(toks, b * toks.astype(a.dtype) ** a)
+
 
 def make_policy_decide(policy: AllocationPolicy, with_observed: bool):
     def decide(a, b, observed):
-        toks = choose_tokens_jnp(a, b, policy,
-                                 observed if with_observed else None)
-        return toks, b * toks.astype(a.dtype) ** a
+        return _policy_rows(a, b, policy, None,
+                            observed if with_observed else None)
 
     return decide
 
 
 def make_priced_decide(policy: AllocationPolicy, with_observed: bool):
     def decide(a, b, price, observed):
-        toks = choose_tokens_priced_jnp(
-            a, b, policy, price, observed if with_observed else None)
-        return toks, b * toks.astype(a.dtype) ** a
+        return _policy_rows(a, b, policy, price,
+                            observed if with_observed else None)
 
     return decide
 
 
 def make_fused_decide(model, policy: AllocationPolicy, with_observed: bool):
     scaler = model.scaler
+    dt = decode_dtype(model)
 
     def fused(params, model_in, observed):
         z = model.serve_apply(params, model_in)
         a, b = scaler.decode(z)
+        if a.dtype != dt or b.dtype != dt:
+            raise TypeError(f"{model.family} decodes (a, b) to "
+                            f"{a.dtype}/{b.dtype}, not its parameters' {dt}")
         a64 = a.astype(jnp.float64)
         b64 = b.astype(jnp.float64)
         toks = choose_tokens_jnp(a64, b64, policy,
                                  observed if with_observed else None)
         rt = b64 * toks.astype(jnp.float64) ** a64
-        return toks, a, b, rt
+        return _pack(toks, a64, b64, rt)
 
     return fused
 
@@ -222,32 +264,8 @@ def make_sharded_policy_per_shard(policy: AllocationPolicy,
                                   with_observed: bool, priced: bool):
     def per_shard(a, b, price, obs):
         # exactly the single-shard policy stage on a (Bp,) block
-        if priced:
-            toks = choose_tokens_priced_jnp(
-                a, b, policy, price, obs if with_observed else None)
-        else:
-            toks = choose_tokens_jnp(
-                a, b, policy, obs if with_observed else None)
-        return toks, b * toks.astype(a.dtype) ** a
-
-    return per_shard
-
-
-def make_sharded_fused_per_shard(model, policy: AllocationPolicy,
-                                 with_observed: bool):
-    scaler = model.scaler
-
-    def per_shard(params, model_in, obs):
-        # the single-shard fused stage on one replica's (Bp, ...)
-        # block: identical shapes, identical math
-        z = model.serve_apply(params, model_in)
-        a, b = scaler.decode(z)
-        a64 = a.astype(jnp.float64)
-        b64 = b.astype(jnp.float64)
-        toks = choose_tokens_jnp(a64, b64, policy,
-                                 obs if with_observed else None)
-        rt = b64 * toks.astype(jnp.float64) ** a64
-        return toks, a, b, rt
+        return _policy_rows(a, b, policy, price if priced else None,
+                            obs if with_observed else None)
 
     return per_shard
 
@@ -363,6 +381,13 @@ class AllocationService:
     def compile_state(self) -> ReplicaState:
         return self.replica
 
+    @functools.cached_property
+    def fused_layout(self) -> Tuple:
+        """Row dtypes of the fused stage's packed output, fixed by the
+        model: tokens, a, b, runtime."""
+        dt = decode_dtype(self.model)
+        return (np.int64, dt, dt, np.float64)
+
     # ------------------------------------------------------------ jit cache --
     def _shape_sig(self, model_in: Dict[str, np.ndarray]) -> Tuple:
         # full padded shapes (batch dim included): one cache entry == one
@@ -451,7 +476,7 @@ class AllocationService:
                     out = fn(jnp.asarray(a64), jnp.asarray(b64),
                              jnp.asarray(p64), obs_j)
                 price_out = np.asarray(price, np.float64)
-        toks, rt = _download(tracer, out)
+        toks, rt = _download(tracer, out, POLICY_LAYOUT)
         toks, rt = toks[:B], rt[:B]
         return AllocationDecision(
             tokens=toks, runtime=rt, a=a, b=np.asarray(b),
@@ -477,7 +502,7 @@ class AllocationService:
                 out = fn(self.model.params,
                          {k: jnp.asarray(v) for k, v in padded.items()},
                          None if obs_p is None else jnp.asarray(obs_p))
-        toks, a, b, rt = _download(tracer, out)
+        toks, a, b, rt = _download(tracer, out, self.fused_layout)
         toks, rt = toks[:B], rt[:B]
         return AllocationDecision(
             tokens=toks, runtime=rt, a=a[:B], b=b[:B],
@@ -623,9 +648,11 @@ class ShardedAllocationService:
         key = ("sharded_fused", self.n_shards, self.model.cache_key, sig,
                with_observed, self.policy, self.mesh is not None)
         return self.service.replica.get_or_build(key, lambda: jax.jit(
+            # the single-shard fused stage on each replica's (Bp, ...)
+            # block: identical shapes, identical math
             self._map_over_shards(
-                make_sharded_fused_per_shard(self.model, self.policy,
-                                             with_observed), 2, True)))
+                make_fused_decide(self.model, self.policy, with_observed),
+                2, True)))
 
     # ------------------------------------------------------------ stacking --
     def _place(self, shard_of: np.ndarray):
@@ -693,7 +720,7 @@ class ShardedAllocationService:
             with jax.enable_x64(True):
                 out = fn(jnp.asarray(a2), jnp.asarray(b2), jnp.asarray(p2),
                          jnp.asarray(obs2))
-        toks, rt = _download(tracer, out)
+        toks, rt = _download(tracer, out, POLICY_LAYOUT)
         toks, rt = toks[shard_of, pos], rt[shard_of, pos]
         return AllocationDecision(
             tokens=toks, runtime=rt, a=a, b=np.asarray(b),
@@ -724,7 +751,7 @@ class ShardedAllocationService:
                 out = fn(self.model.params,
                          {k: jnp.asarray(v) for k, v in stacked.items()},
                          jnp.asarray(obs2))
-        toks, a, b, rt = _download(tracer, out)
+        toks, a, b, rt = _download(tracer, out, self.service.fused_layout)
         toks, rt = toks[shard_of, pos], rt[shard_of, pos]
         return AllocationDecision(
             tokens=toks, runtime=rt, a=a[shard_of, pos], b=b[shard_of, pos],

@@ -251,7 +251,7 @@ def test_the_served_path_records_ids_downloads_and_collections(service,
     by_id = {r.span_id: r for r in recs if r.kind == "span"}
     for d in decides:
         down, disp = downloads[d.span_id], dispatches[d.span_id]
-        assert down.attrs == {"transfers": 4}
+        assert down.attrs == {"transfers": 1}
         assert d.t0 <= disp.t0 <= disp.t1 <= down.t0 <= down.t1 <= d.t1
         assert down.thread == d.thread == by_id[
             by_id[d.parent].parent].thread      # flush, then the batch
